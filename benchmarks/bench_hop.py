@@ -54,6 +54,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import DHP, NBS
+from repro.launch.mesh import auto_mesh
 from repro.utils import tree_nbytes
 
 MB = 1 << 20
@@ -102,7 +103,7 @@ def bench(
     tour_fallbacks = 0
     try:
         nbs = NBS(root)
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = auto_mesh((1,), ("data",))
         nbs.add_node("A", mesh=mesh)
         nbs.add_node("B", mesh=mesh)
         nbs.add_node("C", mesh=None)  # store-hop dest (no mesh -> store path)

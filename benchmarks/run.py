@@ -50,11 +50,12 @@ def bench_train_rows(fast: bool) -> list[tuple[str, float, str]]:
     from repro.core import DHP, NBS, JobStore
     from repro.data import TokenPipeline
     from repro.distributed.steps import batch_shardings, make_init_fn, make_train_step
+    from repro.launch.mesh import auto_mesh
     from repro.optim import AdamWConfig
     import tempfile
 
     cfg = get_smoke_config("qwen3-1.7b")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     oc = AdamWConfig()
     init_fn, _ = make_init_fn(cfg, mesh, oc)
     step_fn, st_sh, m_sh = make_train_step(cfg, mesh, oc, peak_lr=1e-3, warmup=1)

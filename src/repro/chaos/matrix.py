@@ -603,7 +603,7 @@ def run_fleet_cell(cell: dict, tmp: Path) -> None:
             env["PYTHONPATH"] = _src_dir() + (
                 os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""
             )
-            env.setdefault("JAX_PLATFORMS", "cpu")
+            env["JAX_PLATFORMS"] = "cpu"  # the agent never holds the chip
             agent_proc = subprocess.Popen(
                 [sys.executable, "-m", "repro.fabric.agent",
                  "--registry", reg_spec, "--store", str(tmp / "s3"),

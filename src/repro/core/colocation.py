@@ -177,17 +177,14 @@ def match_viirs_to_cris_ref(
 
 
 def match_viirs_to_cris(viirs_pos, cris_los, sat_pos, **kw):
-    """Kernel-accelerated match with jnp fallback."""
-    try:
-        from repro.kernels.colocate.ops import colocate_match
+    """Kernel-accelerated match (Pallas; interpreted off-TPU)."""
+    from repro.kernels.colocate.ops import colocate_match
 
-        half = kw.get("half_angle_deg", CRIS_FOV_DIAMETER_DEG / 2)
-        u = _unit(viirs_pos - sat_pos[None, :]).astype(jnp.float32)
-        idx, cos = colocate_match(u, cris_los.astype(jnp.float32))
-        thr = jnp.cos(jnp.deg2rad(half)).astype(jnp.float32)
-        return idx, cos, cos >= thr
-    except Exception:
-        return match_viirs_to_cris_ref(viirs_pos, cris_los, sat_pos, **kw)
+    half = kw.get("half_angle_deg", CRIS_FOV_DIAMETER_DEG / 2)
+    u = _unit(viirs_pos - sat_pos[None, :]).astype(jnp.float32)
+    idx, cos = colocate_match(u, cris_los.astype(jnp.float32))
+    thr = jnp.cos(jnp.deg2rad(half)).astype(jnp.float32)
+    return idx, cos, cos >= thr
 
 
 # ---------------------------------------------------------------------------

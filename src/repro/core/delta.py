@@ -92,19 +92,13 @@ def _changed_blocks_fn():
     elsewhere — interpret-mode Pallas over GB-scale states would put a
     python-loop on the publish path. Kernel↔oracle equality is enforced by
     tests/test_kernels.py."""
-    try:
-        from repro.kernels.common import use_interpret
-        from repro.kernels.delta_encode.ops import changed_blocks
+    from repro.kernels.common import use_interpret
+    from repro.kernels.delta_encode.ops import changed_blocks
+    from repro.kernels.delta_encode.ref import changed_blocks_ref
 
-        if not use_interpret():
-            return changed_blocks
-        from repro.kernels.delta_encode.ref import changed_blocks_ref
-
+    if use_interpret():
         return changed_blocks_ref
-    except Exception:  # pragma: no cover - fallback path
-        from repro.kernels.delta_encode.ref import changed_blocks_ref
-
-        return changed_blocks_ref
+    return changed_blocks
 
 
 def device_changed_hints(

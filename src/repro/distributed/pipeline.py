@@ -80,14 +80,12 @@ def pipeline_forward(
         # only the last stage's `out` is non-zero; psum broadcasts it
         return jax.lax.psum(out, axis)
 
-    from jax.experimental.shard_map import shard_map
-
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(p_specs, P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(stacked_params, x)
 

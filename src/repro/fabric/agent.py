@@ -217,7 +217,7 @@ class Agent:
         env["PYTHONPATH"] = _src_dir() + (
             os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""
         )
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        env["JAX_PLATFORMS"] = "cpu"  # fabric workers never hold the chip
         if clean_fault_env:
             env.pop(faults.ENV_VAR, None)
         return subprocess.Popen(self._worker_cmd(name, spec), env=env)

@@ -176,6 +176,7 @@ class FabricSupervisor:
         wait: bool = True,
         extra_args: list[str] | None = None,
         socket_path: str | None = None,
+        device: bool = False,
     ) -> WorkerHandle:
         """Provision a worker process and (unless ``wait=False``) wait for
         its server to answer. ``wait=False`` suits racing claimants that may
@@ -187,7 +188,9 @@ class FabricSupervisor:
         address comes back through the ready-file (and the registry, when
         one is configured). ``module`` selects the worker entrypoint —
         ``repro.serve.worker`` provisions a serving worker (same flag
-        surface; ``extra_args`` carries its ``--engine`` spec)."""
+        surface; ``extra_args`` carries its ``--engine`` spec). ``device``
+        lets the worker initialize the accelerator; every other worker is
+        pinned to the host CPU, since one process at a time holds a chip."""
         os.makedirs(self.socket_dir, exist_ok=True)
         ready = os.path.join(self.socket_dir, f"{name}-{uuid.uuid4().hex[:6]}.ready")
         if self.transport == "tcp":
@@ -228,8 +231,8 @@ class FabricSupervisor:
         env["PYTHONPATH"] = _SRC_DIR + (
             os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""
         )
-        # workers are host-CPU nodes; keep their jax single-device and quiet
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        if not device:
+            env["JAX_PLATFORMS"] = "cpu"
         proc = subprocess.Popen(cmd, env=env)
         if self.transport == "tcp":
             host, _, port = bind.rpartition(":")

@@ -45,7 +45,7 @@ from repro.core.jobstore import STATUS_CKPT, STATUS_FINISHED, JobStore, LeaseLos
 from repro.core.nbs import NBS
 from repro.core.preemption import PreemptionNotice
 from repro.fabric.server import NodeServer
-from repro.utils import logger
+from repro.utils import enable_compile_cache, logger
 
 EXIT_FINISHED = 0
 EXIT_PREEMPTED = 43  # graceful: notice honored, CMI published before exit
@@ -251,6 +251,7 @@ def main(argv: list[str] | None = None) -> int:
         raise SystemExit("worker needs --socket or --tcp")
 
     faults.set_role("worker", node=args.name)  # scope inherited fault plans
+    enable_compile_cache()
     nbs = NBS(args.store)
     nbs.add_node(args.name, mesh=None)
     jobstore = JobStore(args.jobstore) if args.jobstore else None

@@ -43,6 +43,7 @@ def _kernel(m_true: int, u_ref, los_ref, idx_ref, cos_ref):
         u_ref[...],
         los_ref[...],
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,  # full f32: argmax of near-ties
         preferred_element_type=jnp.float32,
     )
     col = j * TILE_M + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)

@@ -13,18 +13,18 @@ def use_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-_UINT_FOR_SIZE = {1: np.uint8, 2: np.uint16, 4: np.uint32, 8: np.uint64}
+_INT_FOR_SIZE = {1: np.int8, 2: np.int16, 4: np.int32, 8: np.int64}
 
 
-def bitcast_to_uint(x: jax.Array) -> jax.Array:
-    """Bitwise view of ``x`` as an unsigned int of the same width.
+def bitcast_to_int(x: jax.Array) -> jax.Array:
+    """Bitwise view of ``x`` as a signed int of the same width.
 
     Bitwise (not value) comparison is what delta detection needs: NaN payload
     changes count as changes, -0.0 vs +0.0 count as changes — matching what a
-    byte-level CMI hash would say.
+    byte-level CMI hash would say. Signed, because Mosaic (the TPU kernel
+    compiler) compares no unsigned words.
     """
-    dt = np.dtype(x.dtype)
-    if np.issubdtype(dt, np.unsignedinteger):
+    target = _INT_FOR_SIZE[np.dtype(x.dtype).itemsize]
+    if np.dtype(x.dtype) == target:
         return x
-    target = _UINT_FOR_SIZE[dt.itemsize]
     return jax.lax.bitcast_convert_type(x, target)

@@ -1,4 +1,4 @@
-"""Public wrapper for the delta_encode kernel: shaping, padding, dispatch."""
+"""Public wrapper for the delta_encode kernel: shaping and dispatch."""
 
 from __future__ import annotations
 
@@ -6,38 +6,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.common import bitcast_to_uint, use_interpret
-from repro.kernels.delta_encode.delta_encode import SUB, TILE_E, delta_encode_blocks
+from repro.kernels.common import bitcast_to_int, use_interpret
+from repro.kernels.delta_encode.delta_encode import delta_encode_rows
 from repro.utils import ceil_div
 
 
-def _to_u32_blocks(x: jax.Array, rows: int) -> tuple[jax.Array, int]:
-    """(nblocks, elems) uint32 view of the serializer chunk grid (padded)."""
-    x = bitcast_to_uint(x)
-    if x.ndim == 0:
-        x = x[None]
-    x2 = x.reshape(x.shape[0], -1) if x.ndim > 1 else x[:, None]
-    # widen to u32 lanes: view narrow uints as u32 via zero-extension (cheap,
-    # keeps lane alignment simple; equality is preserved elementwise)
-    if x2.dtype != jnp.uint32:
-        x2 = x2.astype(jnp.uint32) if x2.dtype in (jnp.uint8, jnp.uint16) else (
-            # u64: split into two u32 lanes
-            jnp.stack(
-                [(x2 & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32),
-                 (x2 >> jnp.uint64(32)).astype(jnp.uint32)],
-                axis=-1,
-            ).reshape(x2.shape[0], -1)
-        )
-    n0 = x2.shape[0]
-    nblocks = max(1, ceil_div(n0, rows))
-    pad0 = nblocks * rows - n0
-    x2 = jnp.pad(x2, ((0, pad0), (0, 0)))
-    blocks = x2.reshape(nblocks, rows * x2.shape[1])
-    # pad to kernel tiles
-    nb_pad = ceil_div(nblocks, SUB) * SUB
-    e_pad = ceil_div(blocks.shape[1], TILE_E) * TILE_E
-    blocks = jnp.pad(blocks, ((0, nb_pad - nblocks), (0, e_pad - blocks.shape[1])))
-    return blocks, nblocks
+def _rows_view(x: jax.Array) -> jax.Array:
+    """(rows, cols) bitwise int view that keeps the minor dim (a free reshape)."""
+    x = bitcast_to_int(x)
+    return x.reshape(-1, x.shape[-1]) if x.ndim >= 2 else x.reshape(-1, 1)
 
 
 def changed_blocks(old: jax.Array, new: jax.Array, rows: int, *, interpret: bool | None = None) -> jax.Array:
@@ -48,7 +25,13 @@ def changed_blocks(old: jax.Array, new: jax.Array, rows: int, *, interpret: bool
         raise ValueError(f"dtype mismatch {old.dtype} vs {new.dtype}")
     if interpret is None:
         interpret = use_interpret()
-    ob, nblocks = _to_u32_blocks(jnp.asarray(old), rows)
-    nb_, _ = _to_u32_blocks(jnp.asarray(new), rows)
-    flags = delta_encode_blocks(ob, nb_, interpret=interpret)
-    return flags[:nblocks, 0].astype(bool)
+    n0 = old.shape[0] if old.ndim else 1
+    if old.size == 0:
+        return jnp.zeros(max(1, ceil_div(n0, rows)), bool)
+    ov, nv = _rows_view(jnp.asarray(old)), _rows_view(jnp.asarray(new))
+    per_row = delta_encode_rows(ov, nv, interpret=interpret)[:, 0]
+    # view rows per axis-0 chunk: the chunk grid cuts axis 0 every ``rows``
+    group = rows * (ov.shape[0] // n0)
+    nblocks = max(1, ceil_div(n0, rows))
+    per_row = jnp.pad(per_row, (0, nblocks * group - per_row.shape[0]))
+    return jnp.any(per_row.reshape(nblocks, group) != 0, axis=1)

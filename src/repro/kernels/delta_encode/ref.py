@@ -6,7 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.common import bitcast_to_uint
+from repro.kernels.common import bitcast_to_int
 from repro.utils import ceil_div
 
 
@@ -17,7 +17,7 @@ def to_blocks(x: jax.Array, rows: int) -> jax.Array:
     [i*rows, (i+1)*rows). Trailing partial blocks are zero-padded — both
     operands get identical padding so it never flags a change.
     """
-    x = bitcast_to_uint(x)
+    x = bitcast_to_int(x)
     if x.ndim == 0:
         x = x[None]
     x2 = x.reshape(x.shape[0], -1) if x.ndim > 1 else x[:, None]

@@ -4,14 +4,25 @@ XLA_FLAGS=--xla_force_host_platform_device_count before first jax init)."""
 
 from __future__ import annotations
 
+from collections.abc import Sequence
+
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape: Sequence[int], axes: Sequence[str]):
+    """``jax.make_mesh`` with ``Auto`` axes: shardings propagate as in GSPMD.
+
+    JAX's default is ``Explicit`` axes, under which gathers such as the
+    embedding lookup demand an explicit ``out_sharding``."""
+    return jax.make_mesh(tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 single pod (256 chips) or 2×16×16 two pods (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_debug_mesh(n_data: int = 0, n_model: int = 1):
@@ -19,4 +30,4 @@ def make_debug_mesh(n_data: int = 0, n_model: int = 1):
     n = jax.device_count()
     if n_data <= 0:
         n_data = max(1, n // n_model)
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return auto_mesh((n_data, n_model), ("data", "model"))

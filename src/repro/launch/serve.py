@@ -20,12 +20,13 @@ plus per-request TTFT when routing over workers.
 from __future__ import annotations
 
 import argparse
+import shutil
 import tempfile
 import time
 
 import numpy as np
 
-from repro.utils import logger
+from repro.utils import enable_compile_cache, logger
 
 
 def build_requests(vocab: int, *, batch: int, prompt_len: int, gen: int,
@@ -88,8 +89,10 @@ def run_routed(spec: str, requests: list[dict], *, workers: int,
     from repro.serve.scenarios import spawn_serve_worker
 
     root = tempfile.mkdtemp(prefix="navp-serve-cli-")
-    sup = FabricSupervisor(store_root=root + "/store",
-                           jobstore_root=root + "/jobs", transport=transport)
+    # a model worker builds its weights before it answers: allow a cold
+    # accelerator start (a worker that dies is still caught at once)
+    sup = FabricSupervisor(store_root=root + "/store", jobstore_root=root + "/jobs",
+                           transport=transport, spawn_timeout_s=600.0)
     router = ServeRouter(jobstore=JobStore(root + "/jobs"))
     try:
         for i in range(workers):
@@ -106,6 +109,7 @@ def run_routed(spec: str, requests: list[dict], *, workers: int,
         transcripts = {req["id"]: router.transcript(req["id"])
                        for req in requests}
         ttft = sorted(router.ttft_s.values())
+        status = {name: router.status(name) for name in sorted(router.workers)}
         return {
             "mode": f"routed:{workers}x{transport}",
             "prefill_s": prefill_s,
@@ -114,10 +118,12 @@ def run_routed(spec: str, requests: list[dict], *, workers: int,
             "transcripts": transcripts,
             "ttft_p50_s": ttft[len(ttft) // 2],
             "ttft_max_s": ttft[-1],
+            "workers": status,
         }
     finally:
         router.close()
-        sup.shutdown()
+        sup.shutdown(wait_s=30.0)  # an accelerator worker takes seconds to let go
+        shutil.rmtree(root, ignore_errors=True)
 
 
 def main(argv=None) -> dict:
@@ -138,6 +144,7 @@ def main(argv=None) -> dict:
     ap.add_argument("--publish-every", type=int, default=8,
                     help="CMI publish cadence in decode steps (workers mode)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     spec, vocab = _engine_spec(args)
     requests = build_requests(vocab, batch=args.batch,
@@ -164,6 +171,8 @@ def main(argv=None) -> dict:
     if "ttft_p50_s" in metrics:
         logger.info("TTFT p50 %.1fms max %.1fms",
                     metrics["ttft_p50_s"] * 1e3, metrics["ttft_max_s"] * 1e3)
+        for name, st in metrics["workers"].items():
+            logger.info("worker %s: %s on %s", name, st["engine"], st["platform"])
     for req in requests:
         print(f"{req['id']}: {metrics['transcripts'][req['id']]}")
     return metrics

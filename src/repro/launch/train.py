@@ -21,6 +21,7 @@ Example (laptop scale):
 from __future__ import annotations
 
 import argparse
+import time
 
 import jax
 import numpy as np
@@ -32,17 +33,24 @@ from repro.core.dhp import Preempted
 from repro.core.preemption import PreemptionNotice, SpotSchedule, run_preemptible
 from repro.data import TokenPipeline
 from repro.distributed.steps import batch_shardings, make_init_fn, make_train_step
+from repro.launch.mesh import auto_mesh
 from repro.optim import AdamWConfig
-from repro.utils import logger
+from repro.utils import enable_compile_cache, logger
 
 
 def parse_mesh(spec: str):
     dims = [int(x) for x in spec.split("x")]
     names = ("data", "model") if len(dims) == 2 else ("pod", "data", "model")
-    return jax.make_mesh(tuple(dims), names[: len(dims)])
+    return auto_mesh(dims, names[: len(dims)])
 
 
-def build_worker(args, cfg, store, nbs, schedule, notice, job_id, mesh_specs):
+def build_worker(args, cfg, store, nbs, schedule, notice, job_id, mesh_specs,
+                 history: list | None = None):
+    """Worker factory for :func:`run_preemptible`. With ``history``, every
+    step appends ``{step, loss, step_s, publish_s}``: the loss as a float,
+    the step's wall time up to the loss reaching the host, and the time the
+    loop stood still for a publish (0.0 on steps without one)."""
+
     def make_worker(incarnation: int):
         def worker():
             mesh = parse_mesh(mesh_specs[min(incarnation, len(mesh_specs) - 1)])
@@ -83,17 +91,25 @@ def build_worker(args, cfg, store, nbs, schedule, notice, job_id, mesh_specs):
             )
             loss = float("nan")
             while int(state["step"]) < args.steps:
+                t0 = time.perf_counter()
                 step = int(state["step"])
                 batch, _ = pipe.batch_at({"data_step": int(state["data"]["data_step"]), "seed": args.seed})
                 batch = jax.tree_util.tree_map(jax.device_put, batch, b_sh)
                 state, metrics = jstep(state, batch)
                 step += 1
                 loss = float(metrics["loss"])
+                step_s = time.perf_counter() - t0
                 if args.log_every and step % args.log_every == 0:
                     logger.info("step %d loss %.4f lr %.2e", step, loss, float(metrics["lr"]))
                 preempting = notice.imminent() or schedule.should_preempt(step)
+                publish_s = 0.0
                 if step % args.publish_every == 0 or preempting or step >= args.steps:
+                    t0 = time.perf_counter()
                     dhp.publish(job_id, "ckpt", state, step=step)
+                    publish_s = time.perf_counter() - t0
+                if history is not None:
+                    history.append({"step": step, "loss": loss, "step_s": step_s,
+                                    "publish_s": publish_s})
                 if preempting and step < args.steps:
                     dhp.flush()
                     store.release(job_id)
@@ -111,7 +127,7 @@ def build_worker(args, cfg, store, nbs, schedule, notice, job_id, mesh_specs):
     return make_worker
 
 
-def main(argv=None) -> float:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true", help="reduced config (CPU)")
@@ -133,9 +149,12 @@ def main(argv=None) -> float:
     ap.add_argument("--no-delta", action="store_true")
     ap.add_argument("--async-publish", action="store_true")
     ap.add_argument("--log-every", type=int, default=5)
-    args = ap.parse_args(argv)
+    return ap
 
-    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
+
+def run_job(args, cfg, *, history: list | None = None) -> tuple[float, str]:
+    """Create (or take ``--job-id``) one job and drive it to "finished"
+    through the Fig.-7 loop; returns ``(final loss, job id)``."""
     store = JobStore(args.store)
     nbs = NBS(args.store + "/nbs")
     job_id = args.job_id
@@ -149,13 +168,21 @@ def main(argv=None) -> float:
     notice = PreemptionNotice()
     notice.install_sigterm()
     mesh_specs = (args.remesh or args.mesh).split(",")
-    make_worker = build_worker(args, cfg, store, nbs, schedule, notice, job_id, mesh_specs)
+    make_worker = build_worker(args, cfg, store, nbs, schedule, notice, job_id, mesh_specs,
+                               history)
     loss, incarnations = run_preemptible(make_worker)
     logger.info(
         "job %s finished: loss=%.4f after %d incarnation(s); jobs=%s",
         job_id, loss, incarnations, store.svc_list_jobs(),
     )
-    return loss
+    return loss, job_id
+
+
+def main(argv=None) -> float:
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
+    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
+    return run_job(args, cfg)[0]
 
 
 if __name__ == "__main__":

@@ -64,6 +64,7 @@ class ToyEngine:
     """
 
     kind = "toy"
+    platform = "cpu"  # numpy on the host
 
     def __init__(self, d: int = 64, vocab: int = 512, seed: int = 0):
         self.d, self.vocab, self.seed = int(d), int(vocab), int(seed)
@@ -139,12 +140,16 @@ class ModelEngine:
         self.cfg = get_smoke_config(arch) if smoke else get_config(arch)
         if self.cfg.vision_prefix or self.cfg.encdec:
             raise ValueError(f"serving supports decoder-only archs, not {arch!r}")
-        self.model = Model(self.cfg)
-        self.params, _ = self.model.init(jax.random.PRNGKey(self.seed))
+        self.model = model = Model(self.cfg)
+        # one compiled init: op-by-op, each fp32 weight and its random
+        # temporaries sit on the device beside the finished bf16 ones
+        self.params = jax.jit(lambda key: model.init(key)[0])(jax.random.PRNGKey(self.seed))
+        # where decode runs: the device the weights landed on
+        self.platform = jax.tree_util.tree_leaves(self.params)[0].devices().pop().platform
         self.vocab = self.cfg.vocab
-        self._decode_fn = jax.jit(
-            lambda p, c, t, pos: self.model.decode(p, c, t, pos)
-        )
+        # bound to the model, not to self: a cycle through the engine would
+        # keep its weights on the device until the garbage collector ran
+        self._decode_fn = jax.jit(model.decode)
 
     def spec(self) -> str:
         return f"model:{self.arch}:{'smoke' if self.smoke else 'full'}:seed={self.seed}"

@@ -80,6 +80,10 @@ class ServeRouter:
         except (OSError, wire.WireError) as e:
             raise WorkerLost(name, e) from e
 
+    def status(self, name: str) -> dict:
+        """The worker's ``svc/serve_status``: engine, platform, counters."""
+        return self._call(name, "svc/serve_status")
+
     def load(self, name: str) -> int:
         return sum(1 for r, w in self.assignment.items()
                    if w == name and r not in self.finished)

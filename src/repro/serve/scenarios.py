@@ -37,7 +37,8 @@ def spawn_serve_worker(
     grace_s: float = 120.0,
     wait: bool = True,
 ):
-    """Provision one serving worker through the supervisor."""
+    """Provision one serving worker through the supervisor. A model engine
+    runs on the accelerator, so its worker is the one process holding it."""
     return sup.spawn(
         name,
         module=SERVE_MODULE,
@@ -46,6 +47,7 @@ def spawn_serve_worker(
         grace_s=grace_s,
         wait=wait,
         socket_path=socket_path,
+        device=engine_spec.startswith("model:"),
         extra_args=["--engine", engine_spec,
                     "--serve-chunk-bytes", str(int(chunk_bytes))],
     )

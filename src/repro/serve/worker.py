@@ -61,7 +61,7 @@ import numpy as np
 from repro.chaos import faults
 from repro.core.jobstore import STATUS_CKPT, STATUS_FINISHED
 from repro.serve.engine import is_done, make_engine, transcript
-from repro.utils import logger
+from repro.utils import enable_compile_cache, logger
 
 EXIT_FINISHED = 0
 EXIT_PREEMPTED = 43
@@ -180,6 +180,7 @@ class ServeHost:
             return {
                 "node": self.node_name,
                 "engine": self.engine.spec(),
+                "platform": self.engine.platform,
                 "counters": dict(self.counters),
                 "requests": {
                     req_id: {"pos": int(st["pos"]), "done": int(st["done"]),
@@ -445,6 +446,7 @@ def main(argv: list[str] | None = None) -> int:
         raise SystemExit("serve worker needs --socket or --tcp")
 
     faults.set_role("worker", node=args.name)
+    enable_compile_cache()
     engine = make_engine(args.engine)
 
     from repro.core.dhp import DHP

@@ -7,6 +7,7 @@ import logging
 import math
 import os
 import time
+from pathlib import Path
 from typing import Any, Iterable
 
 import jax
@@ -18,6 +19,26 @@ if not logger.handlers:  # configure once; launchers may reconfigure
     _h.setFormatter(logging.Formatter("%(asctime)s %(name)s %(levelname)s %(message)s"))
     logger.addHandler(_h)
     logger.setLevel(os.environ.get("REPRO_LOGLEVEL", "INFO"))
+
+
+# the checkout root (src/repro/utils.py -> ../..)
+REPO_ROOT = Path(__file__).resolve().parents[2]
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``$JAX_COMPILATION_CACHE_DIR``, when set, is read by JAX itself and left
+    alone. Otherwise the cache lives at the fixed ``<checkout>/.jax_cache``,
+    never a temp dir or a name with a pid or a time in it, so the next run
+    finds what this one wrote. Call before the first compile.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(REPO_ROOT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 # ---------------------------------------------------------------------------

@@ -65,9 +65,7 @@ def test_collectives_counted_with_trip_multiplier():
 
         return jax.lax.scan(step, x, None, length=4)[0]
 
-    from jax.experimental.shard_map import shard_map
-
-    f = shard_map(body, mesh=mesh, in_specs=P(), out_specs=P())
+    f = jax.shard_map(body, mesh=mesh, in_specs=P(), out_specs=P())
     r = analyze_hlo(_hlo(jax.jit(f), jax.ShapeDtypeStruct((64,), jnp.float32)))
     # 4 iterations -> 4 all-reduces (XLA may elide for 1 device; accept >= 0
     # but if present, the count must reflect the trip multiplier)

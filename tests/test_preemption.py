@@ -14,13 +14,13 @@ import repro.launch.train as T
 # run A: straight through
 lossA = T.main([
     "--arch", "qwen3-1.7b", "--smoke", "--steps", "12", "--publish-every", "4",
-    "--store", "/tmp/navp-eq-a", "--seq-len", "32", "--batch", "4",
+    "--store", STORE_A, "--seq-len", "32", "--batch", "4",
     "--log-every", "0",
 ])
 # run B: preempted at step 7, resumed
 lossB = T.main([
     "--arch", "qwen3-1.7b", "--smoke", "--steps", "12", "--publish-every", "4",
-    "--store", "/tmp/navp-eq-b", "--seq-len", "32", "--batch", "4",
+    "--store", STORE_B, "--seq-len", "32", "--batch", "4",
     "--preempt-at", "7", "--log-every", "0",
 ])
 assert lossA == lossB, (lossA, lossB)
@@ -28,10 +28,11 @@ assert lossA == lossB, (lossA, lossB)
 # compare final published params bitwise
 from repro.core.cmi import restore_cmi
 from repro.core.jobstore import JobStore
-pa = JobStore("/tmp/navp-eq-a"); pb = JobStore("/tmp/navp-eq-b")
-ja = pa.read_job("1"); jb = pb.read_job("1")
-sa, _ = restore_cmi(pa.cmi_root("1"), ja.cmi)
-sb, _ = restore_cmi(pb.cmi_root("1"), jb.cmi)
+pa = JobStore(STORE_A); pb = JobStore(STORE_B)
+(ida, _), = pa.svc_list_jobs(); (idb, _), = pb.svc_list_jobs()
+ja = pa.read_job(ida); jb = pb.read_job(idb)
+sa, _ = restore_cmi(pa.cmi_root(ida), ja.cmi)
+sb, _ = restore_cmi(pb.cmi_root(idb), jb.cmi)
 for x, y in zip(jax.tree_util.tree_leaves(sa["params"]), jax.tree_util.tree_leaves(sb["params"])):
     assert np.asarray(x).tobytes() == np.asarray(y).tobytes()
 print("BITWISE_OK", lossA)
@@ -41,7 +42,7 @@ ELASTIC = r"""
 import repro.launch.train as T
 loss = T.main([
     "--arch", "granite-moe-1b-a400m", "--smoke", "--steps", "10",
-    "--publish-every", "3", "--store", "/tmp/navp-elastic",
+    "--publish-every", "3", "--store", STORE,
     "--seq-len", "32", "--batch", "8", "--preempt-at", "5",
     "--remesh", "4x2,2x2", "--log-every", "0",
 ])
@@ -51,14 +52,15 @@ print("ELASTIC_OK", loss)
 """
 
 
-def test_preempted_run_is_bitwise_identical(subproc):
-    out = subproc(TRAIN_EQUIV, devices=1, timeout=600)
+def test_preempted_run_is_bitwise_identical(subproc, tmp_path):
+    stores = f"STORE_A = {str(tmp_path / 'a')!r}\nSTORE_B = {str(tmp_path / 'b')!r}\n"
+    out = subproc(stores + TRAIN_EQUIV, devices=1, timeout=600)
     assert "BITWISE_OK" in out
 
 
-def test_elastic_restart_on_smaller_mesh(subproc):
+def test_elastic_restart_on_smaller_mesh(subproc, tmp_path):
     """Preempt on a 4x2 mesh, resume on 2x2 — the spot-reclaim downsize."""
-    out = subproc(ELASTIC, devices=8, timeout=600)
+    out = subproc(f"STORE = {str(tmp_path)!r}\n" + ELASTIC, devices=8, timeout=600)
     assert "ELASTIC_OK" in out
 
 
