@@ -13,7 +13,6 @@ Sections map to the paper's experiments (DESIGN.md §7):
     bench_serve    — elastic serving: tokens/s + TTFT under migration/resume churn
     bench_colocate — Exp 1: VIIRS→CrIS co-location stages + match kernel
     bench_train    — end-to-end smoke train step + publish cadence overhead
-    roofline       — §Roofline table from the dry-run artifacts (if present)
 
 ``--lint`` gates the run on navlint (``python -m repro.analysis``): the
 migration-safety lint over src/ + examples/ plus the fault-coverage
@@ -141,18 +140,6 @@ def main() -> None:
             json.dump(serve_results, f, indent=1, sort_keys=True)
     _section("colocate", bench_colocate.run(2 if fast else 4))
     _section("train", bench_train_rows(fast))
-    # roofline table (requires dry-run artifacts)
-    try:
-        from benchmarks import roofline
-
-        rows = [r for r in (roofline.roofline_row(c) for c in roofline.load_cells()) if r]
-        for r in rows:
-            print(
-                f"roofline.{r['arch']}.{r['shape']},0.0,"
-                f"dom={r['dominant']} frac={r['roofline_frac']:.3f} useful={r['useful_ratio']:.2f}"
-            )
-    except Exception as e:  # dry-run artifacts absent
-        print(f"roofline.skipped,0.0,{e}")
 
 
 if __name__ == "__main__":

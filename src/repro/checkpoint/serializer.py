@@ -79,6 +79,7 @@ from repro.checkpoint.format import (
     dtype_to_str,
     encode_structure,
 )
+from repro.spans import annotate, span
 from repro.utils import content_hash, crc32_of, flatten_with_paths, logger
 
 DATA_FILE = "data-0.bin"  # shard 0; also the only file in seed-format CMIs
@@ -176,7 +177,8 @@ def _unique_shards(x: Any) -> list[tuple[tuple[tuple[int, int], ...], np.ndarray
     for shard in x.addressable_shards:
         key = _norm_index(shard.index, shape)
         if key not in seen:
-            seen[key] = _contig(np.asarray(shard.data))
+            with span("ckpt.d2h"):
+                seen[key] = _contig(np.asarray(shard.data))
     return sorted(seen.items(), key=lambda kv: kv[0])
 
 
@@ -624,7 +626,8 @@ def iter_state_chunks(
             ch = StateChunk(seq, path, [list(s) for s in bslice], None, nbytes,
                             baseline[key], None, True)
         else:
-            h, crc, comp = fut.result() if fut is not None else hash_task(buf, key)
+            with span("ckpt.hash_wait"):
+                h, crc, comp = fut.result() if fut is not None else hash_task(buf, key)
             if baseline.get(key) == h:
                 ch = StateChunk(seq, path, [list(s) for s in bslice], None, nbytes,
                                 h, crc, True)
@@ -971,7 +974,7 @@ def _save_checkpoint_cas(
         )
     baseline = {key: c.hash for key, c in parent_chunks.items()}
     changed_hint = opts.changed_hint if parent_chunks else {}
-    stats = {"written_bytes": 0, "ref_bytes": 0, "chunks": 0, "ref_chunks": 0,
+    stats = {"bytes": 0, "written_bytes": 0, "ref_bytes": 0, "chunks": 0, "ref_chunks": 0,
              "dedup_chunks": 0, "objects_written": 0}
 
     with store.publish_guard():
@@ -998,11 +1001,15 @@ def _save_checkpoint_cas(
                     ref=object_ref(digest),
                 ))
                 stats["chunks"] += 1
+                stats["bytes"] += ch.nbytes
                 if ch.data is None:  # baseline ref, hint ref, or dedup hit
                     stats["ref_chunks"] += 1
                     stats["ref_bytes"] += ch.nbytes
                     if ch.dup:
                         stats["dedup_chunks"] += 1
+                elif pool.q.full():  # the writers are behind: this put blocks
+                    with span("ckpt.write_wait"):
+                        pool.submit(digest, ch.data)
                 else:
                     pool.submit(digest, ch.data)
         except BaseException:
@@ -1011,7 +1018,8 @@ def _save_checkpoint_cas(
             except Exception:
                 pass  # the original failure is the one worth surfacing
             raise
-        stats["written_bytes"], stats["objects_written"] = pool.close()
+        with span("ckpt.write_wait"):
+            stats["written_bytes"], stats["objects_written"] = pool.close()
         faults.fire("cas.publish.post_objects")
 
         manifest = Manifest(
@@ -1024,8 +1032,10 @@ def _save_checkpoint_cas(
             data_files=[],
             extra={"stats": stats},
         )
-        with CommitScope(final, crash_after_data=_crash_after_data) as scope:
+        with span("ckpt.commit"), \
+                CommitScope(final, crash_after_data=_crash_after_data) as scope:
             scope.write_text("manifest.json", manifest.dumps())
+    annotate(**stats)
     logger.debug(
         "saved CAS CMI %s: %d chunks (%d ref'd, %d dedup'd), %d new objects, "
         "%.1f MiB written",

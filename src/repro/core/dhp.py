@@ -54,6 +54,7 @@ from repro.core.cmi import mesh_resharding_resolver, restore_cmi, save_cmi, snap
 from repro.core.delta import DeltaPolicy, DeltaTracker
 from repro.core.jobstore import STATUS_CKPT, STATUS_FINISHED, JobStore
 from repro.core.nbs import NBS, RemoteStateRef
+from repro.spans import span
 from repro.utils import logger
 
 
@@ -325,45 +326,47 @@ class DHP:
         """
         if self.jobstore is None:
             raise RuntimeError("publish requires a JobStore")
-        if status == STATUS_CKPT:
-            if state is None:
-                raise ValueError('publish(status="ckpt") needs state')
-            name = f"cmi-{step:010d}-{uuid.uuid4().hex[:8]}"
-            parent = self.delta.parent_for(job_id, self.jobstore)
-            # Durable publishes are content-addressed (manifest v4): chunks
-            # land once in the job store's objects/ tree and successive
-            # publishes write only the digests the store does not already
-            # hold — the O(changed) publish that makes the paper's C cheap.
-            opts = SaveOptions(
-                chunk_bytes=self.chunk_bytes,
-                parent=parent,
-                changed_hint=changed_hint or {},
-                writers=self.writers,
-                cas=True,
-            )
-            self.nbs.plugins.emit("on_checkpoint", node=self.node, cmi=name, step=step)
-            if self.async_publish:
-                host_state = snapshot_to_host(state)
-                self._submit(self._do_publish_ckpt, job_id, name, host_state, step, meta, opts)
-            else:
-                self._do_publish_ckpt(job_id, name, state, step, meta, opts)
-            self.delta.record_published(job_id, name)
-            return name
-        if status == STATUS_FINISHED:
-            self.flush()  # never finish before earlier ckpts land
-            name = None
-            if product is not None:
-                name = f"product-{uuid.uuid4().hex[:8]}"
-                save_cmi(
-                    self.jobstore.cmi_root(job_id), name, product, step=step,
-                    meta={"kind": "product", **(meta or {})},
-                    options=SaveOptions(chunk_bytes=self.chunk_bytes,
-                                        writers=self.writers, cas=True),
+        with span("dhp.publish", job=job_id, step=step, status=status):
+            if status == STATUS_CKPT:
+                if state is None:
+                    raise ValueError('publish(status="ckpt") needs state')
+                name = f"cmi-{step:010d}-{uuid.uuid4().hex[:8]}"
+                parent = self.delta.parent_for(job_id, self.jobstore)
+                # Durable publishes are content-addressed (manifest v4): chunks
+                # land once in the job store's objects/ tree and successive
+                # publishes write only the digests the store does not already
+                # hold — the O(changed) publish that makes the paper's C cheap.
+                opts = SaveOptions(
+                    chunk_bytes=self.chunk_bytes,
+                    parent=parent,
+                    changed_hint=changed_hint or {},
+                    writers=self.writers,
+                    cas=True,
                 )
-            self.jobstore.svc_publish_job(job_id, STATUS_FINISHED, product=name, step=step)
-            self.nbs.plugins.emit("on_publish", job_id=job_id, status=status, name=name)
-            return name
-        raise ValueError(f"unknown publish status {status!r}")
+                self.nbs.plugins.emit("on_checkpoint", node=self.node, cmi=name, step=step)
+                if self.async_publish:
+                    host_state = snapshot_to_host(state)
+                    self._submit(self._do_publish_ckpt, job_id, name, host_state, step, meta, opts)
+                else:
+                    self._do_publish_ckpt(job_id, name, state, step, meta, opts)
+                self.delta.record_published(job_id, name)
+                return name
+            if status == STATUS_FINISHED:
+                self.flush()  # never finish before earlier ckpts land
+                name = None
+                if product is not None:
+                    name = f"product-{uuid.uuid4().hex[:8]}"
+                    save_cmi(
+                        self.jobstore.cmi_root(job_id), name, product, step=step,
+                        meta={"kind": "product", **(meta or {})},
+                        options=SaveOptions(chunk_bytes=self.chunk_bytes,
+                                            writers=self.writers, cas=True),
+                    )
+                with span("jobstore.record"):
+                    self.jobstore.svc_publish_job(job_id, STATUS_FINISHED, product=name, step=step)
+                self.nbs.plugins.emit("on_publish", job_id=job_id, status=status, name=name)
+                return name
+            raise ValueError(f"unknown publish status {status!r}")
 
     def publish_ref(self, job_id: str, ref: RemoteStateRef, *, step: int = 0,
                     extra: dict | None = None, meta: dict | None = None) -> str:
@@ -408,10 +411,11 @@ class DHP:
         # chaos point: the CMI is committed but the job record does not name
         # it yet — a kill here must leave the PREVIOUS publish authoritative
         faults.fire("publish.before_record")
-        self.jobstore.svc_publish_job(
-            job_id, STATUS_CKPT, cmi=name, step=step,
-            keep_last=self.delta.policy.keep_last,
-        )
+        with span("jobstore.record"):
+            self.jobstore.svc_publish_job(
+                job_id, STATUS_CKPT, cmi=name, step=step,
+                keep_last=self.delta.policy.keep_last,
+            )
         self.nbs.plugins.emit("on_publish", job_id=job_id, status=STATUS_CKPT, name=name)
 
     # ------------------------------------------------------------------
@@ -422,14 +426,15 @@ class DHP:
         if self.jobstore is None:
             raise RuntimeError("restart requires a JobStore")
         node = node or self.node
-        job = self.jobstore.read_job(job_id)
-        if job.cmi is None:
-            raise ValueError(f"job {job_id} has no published CMI")
-        mesh = self.nbs.node(node).mesh
-        state, manifest = restore_cmi(
-            self.jobstore.cmi_root(job_id), job.cmi, mesh=mesh,
-            io_threads=self.io_threads,
-        )
+        with span("dhp.restart", job=job_id):
+            job = self.jobstore.read_job(job_id)
+            if job.cmi is None:
+                raise ValueError(f"job {job_id} has no published CMI")
+            mesh = self.nbs.node(node).mesh
+            state, manifest = restore_cmi(
+                self.jobstore.cmi_root(job_id), job.cmi, mesh=mesh,
+                io_threads=self.io_threads,
+            )
         self.nbs.plugins.emit("on_restart", node=node, cmi=job.cmi, step=manifest.step)
         self.delta.record_published(job_id, job.cmi)  # future deltas chain here
         return state, manifest.step

@@ -21,7 +21,6 @@ Example (laptop scale):
 from __future__ import annotations
 
 import argparse
-import time
 
 import jax
 import numpy as np
@@ -35,6 +34,7 @@ from repro.data import TokenPipeline
 from repro.distributed.steps import batch_shardings, make_init_fn, make_train_step
 from repro.launch.mesh import auto_mesh
 from repro.optim import AdamWConfig
+from repro.spans import last, span
 from repro.utils import enable_compile_cache, logger
 
 
@@ -48,8 +48,9 @@ def build_worker(args, cfg, store, nbs, schedule, notice, job_id, mesh_specs,
                  history: list | None = None):
     """Worker factory for :func:`run_preemptible`. With ``history``, every
     step appends ``{step, loss, step_s, publish_s}``: the loss as a float,
-    the step's wall time up to the loss reaching the host, and the time the
-    loop stood still for a publish (0.0 on steps without one)."""
+    the step's wall time up to the loss reaching the host (the ``train.step``
+    span), and the time the loop stood still for a publish (the
+    ``dhp.publish`` span; 0.0 on steps without one)."""
 
     def make_worker(incarnation: int):
         def worker():
@@ -91,22 +92,21 @@ def build_worker(args, cfg, store, nbs, schedule, notice, job_id, mesh_specs,
             )
             loss = float("nan")
             while int(state["step"]) < args.steps:
-                t0 = time.perf_counter()
-                step = int(state["step"])
-                batch, _ = pipe.batch_at({"data_step": int(state["data"]["data_step"]), "seed": args.seed})
-                batch = jax.tree_util.tree_map(jax.device_put, batch, b_sh)
-                state, metrics = jstep(state, batch)
-                step += 1
-                loss = float(metrics["loss"])
-                step_s = time.perf_counter() - t0
+                with span("train.step", job=job_id) as sp:
+                    step = int(state["step"])
+                    batch, _ = pipe.batch_at({"data_step": int(state["data"]["data_step"]), "seed": args.seed})
+                    batch = jax.tree_util.tree_map(jax.device_put, batch, b_sh)
+                    state, metrics = jstep(state, batch)
+                    step += 1
+                    loss = float(metrics["loss"])
+                step_s = sp.duration
                 if args.log_every and step % args.log_every == 0:
                     logger.info("step %d loss %.4f lr %.2e", step, loss, float(metrics["lr"]))
                 preempting = notice.imminent() or schedule.should_preempt(step)
                 publish_s = 0.0
                 if step % args.publish_every == 0 or preempting or step >= args.steps:
-                    t0 = time.perf_counter()
                     dhp.publish(job_id, "ckpt", state, step=step)
-                    publish_s = time.perf_counter() - t0
+                    publish_s = last().duration
                 if history is not None:
                     history.append({"step": step, "loss": loss, "step_s": step_s,
                                     "publish_s": publish_s})

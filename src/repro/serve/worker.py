@@ -53,7 +53,6 @@ import os
 import signal
 import sys
 import threading
-import time
 from typing import Any
 
 import numpy as np
@@ -61,6 +60,7 @@ import numpy as np
 from repro.chaos import faults
 from repro.core.jobstore import STATUS_CKPT, STATUS_FINISHED
 from repro.serve.engine import is_done, make_engine, transcript
+from repro.spans import span
 from repro.utils import enable_compile_cache, logger
 
 EXIT_FINISHED = 0
@@ -129,9 +129,8 @@ class ServeHost:
             faults.fire("serve.admit")
             if req_id in self.active:
                 raise ValueError(f"request {req_id!r} already active")
-            t0 = time.perf_counter()
-            state = self.engine.prefill(np.asarray(prompt, np.int32), int(max_new))
-            prefill_s = time.perf_counter() - t0
+            with span("engine.prefill", job=job_id) as sp:
+                state = self.engine.prefill(np.asarray(prompt, np.int32), int(max_new))
             self.counters["prefills"] += 1
             self.active[req_id] = state
             if job_id is not None:
@@ -145,7 +144,7 @@ class ServeHost:
                 "tokens": [[0, int(state["out"][0])]],
                 "pos": int(state["pos"]),
                 "done": int(state["done"]),
-                "prefill_s": prefill_s,
+                "prefill_s": sp.duration,
                 "prompt_tokens": int(np.asarray(prompt).size),
             }
 
@@ -160,7 +159,8 @@ class ServeHost:
                 if is_done(state):
                     finished.append(req_id)
                     continue
-                state = self.engine.decode(state)
+                with span("engine.decode", job=self.jobs.get(req_id)):
+                    state = self.engine.decode(state)
                 self.active[req_id] = state
                 self.counters["decode_steps"] += 1
                 tokens[req_id] = [[int(state["done"]) - 1, int(state["tok"])]]
