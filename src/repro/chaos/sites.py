@@ -60,7 +60,7 @@ SITES: dict[str, str] = {
     # -- cas (content-addressed object store, manifest v4) -----------------
     "cas.publish.pre_link": "per new object: after tmp fsync, before the atomic link",
     "cas.publish.post_objects": "all objects durable, before the manifest commit",
-    "cas.gc.mid_sweep": "in the mark-and-sweep GC, before each object unlink",
+    "cas.gc.mid_sweep": "on the GC's reclaimer thread, before each unlink of a detached object",
     # -- wire, continued: compressed bulk payloads -------------------------
     "wire.bulk.decompress": "receiver side, on each compressed bulk payload before decompression",
     # -- serve (elastic serving fleet: continuous batching + migration) ----

@@ -29,24 +29,45 @@ with identical bytes and the second ``os.replace`` is a no-op overwrite.
 Publisher/GC coordination uses the store's existing fcntl discipline: a
 publisher holds a *shared* ``flock`` on ``objects/.lock`` across object
 writes and the manifest commit, while the mark-and-sweep GC takes it
-*exclusive* — a sweep can never delete objects a mid-commit publisher is
+*exclusive* — a sweep can never detach objects a mid-commit publisher is
 about to reference, and a SIGKILLed holder releases the lock with the
 process.
+
+The sweep is split in two. *Detach*, under the exclusive lock, renames
+each unreferenced object out of the tree into ``<store_root>/.reclaim/`` —
+a sibling of ``objects/`` on the same filesystem, which no listing of
+objects, CMIs, staging dirs or fsck walks. A rename frees no blocks; when
+detach returns the object tree is exactly what unlinking would have left.
+The objects it considers are those of the manifests the GC just dropped,
+so the publish path lists no directory of the tree. *Reclaim* — the
+unlinks, whose cost is the filesystem freeing each file's blocks — runs on
+one lazily started daemon thread per process (:data:`RECLAIMER`), off the
+publish path and without any store lock: nothing references a detached
+file. The same thread runs the sweeps that walk the whole tree, for what
+no dropped manifest names (objects and ``.tmp-*`` files of killed
+publishers). A process killed with files still in ``.reclaim/`` strands
+only unreferenced bytes; the job's next sweep hands the directory over
+again.
 """
 
 from __future__ import annotations
 
 import fcntl
+import itertools
 import os
 import queue
 import threading
 from pathlib import Path
 
 from repro.chaos import faults
+from repro.spans import annotate, span
+from repro.utils import logger
 
 OBJECTS_DIR = "objects"
+RECLAIM_DIR = ".reclaim"
 _LOCK_FILE = ".lock"
 _TMP_PREFIX = ".tmp-"
+_detach_seq = itertools.count()
 
 
 def object_ref(digest: str) -> str:
@@ -64,14 +85,15 @@ def is_object_ref(ref: str | None) -> bool:
     return ref is not None and ref.startswith(OBJECTS_DIR + "/")
 
 
+def referenced_objects(manifest) -> dict[str, int]:
+    """Every object a manifest's chunk table references, with its bytes."""
+    return {c.file: c.nbytes for aentry in manifest.arrays.values()
+            for c in aentry.chunks if is_object_ref(c.ref)}
+
+
 def referenced_digests(manifest) -> set[str]:
     """All object digests a manifest's chunk table references (GC mark set)."""
-    out: set[str] = set()
-    for aentry in manifest.arrays.values():
-        for c in aentry.chunks:
-            if is_object_ref(c.ref):
-                out.add(c.file)
-    return out
+    return set(referenced_objects(manifest))
 
 
 class ObjectStore:
@@ -80,6 +102,7 @@ class ObjectStore:
     def __init__(self, root: str | os.PathLike):
         self.root = Path(root)
         self.dir = self.root / OBJECTS_DIR
+        self.reclaim_dir = self.root / RECLAIM_DIR
 
     def path(self, digest: str) -> Path:
         return self.dir / digest[:2] / digest
@@ -162,24 +185,140 @@ class ObjectStore:
         """Exclusive lock: held by the GC across mark + sweep."""
         return _StoreLock(self._lock_fd(), fcntl.LOCK_EX)
 
-    def sweep(self, keep: set[str]) -> list[str]:
-        """Delete every linked object not in ``keep`` (plus stale tmp files).
+    def sweep(self, keep: set[str], candidates: dict[str, int] | None = None) -> list[str]:
+        """Detach unreferenced objects and hand them to :data:`RECLAIMER`;
+        returns the detached digests.
 
-        Caller must hold :meth:`sweep_guard`. ``cas.gc.mid_sweep`` fires
-        before each unlink — a SIGKILL mid-sweep strands only *unreferenced*
-        objects, which the next sweep (or ``fsck``) accounts for; referenced
-        objects are never touched.
+        With ``candidates`` (digest -> bytes, the objects of the manifests
+        just dropped) only those not in ``keep`` are detached: no walk of the
+        tree, whose listing costs a syscall or more a bucket. Without, every
+        linked object not in ``keep`` and every stale tmp file is.
+
+        Caller must hold :meth:`sweep_guard`. Each file is renamed into
+        :attr:`reclaim_dir` under a name of its own (digest, pid, counter):
+        a digest can be detached, written again by a later publish and
+        detached again before the first copy is reclaimed, and a rename
+        onto an existing name would unlink that one here, synchronously.
+        Counts ``detached_objects`` and ``detached_bytes`` onto the
+        caller's open span.
         """
+        if candidates is None:
+            victims = [(p, None) for p in self.tmp_files()]
+            victims += [(self.path(d), None) for d in self.digests() if d not in keep]
+        else:
+            victims = [(self.path(d), n) for d, n in sorted(candidates.items()) if d not in keep]
         removed: list[str] = []
-        for tmp in self.tmp_files():
-            tmp.unlink(missing_ok=True)
-        for digest in self.digests():
-            if digest in keep:
+        detached: list[tuple[Path, int]] = []
+        if victims:
+            self.reclaim_dir.mkdir(exist_ok=True)
+        for src, size in victims:
+            dst = self.reclaim_dir / f"{src.name}.{os.getpid()}.{next(_detach_seq)}"
+            try:
+                if size is None:
+                    size = src.stat().st_size
+                os.rename(src, dst)
+            except FileNotFoundError:  # detached already, by another sweep
                 continue
-            faults.fire("cas.gc.mid_sweep")
-            self.path(digest).unlink(missing_ok=True)
-            removed.append(digest)
+            detached.append((dst, size))
+            if not src.name.startswith(_TMP_PREFIX):
+                removed.append(src.name)
+        annotate(detached_objects=len(detached), detached_bytes=sum(n for _, n in detached))
+        if detached or self.reclaim_dir.is_dir():
+            RECLAIMER.hand_over(self.reclaim_dir, detached)
         return removed
+
+
+class Reclaimer:
+    """The GC's background work, on one daemon thread started by the first
+    call that queues some.
+
+    Each pass over a reclaim directory unlinks everything in it: the files
+    handed over and whatever a killed process left there.
+    ``cas.gc.mid_sweep`` fires before each unlink. :attr:`backlog_bytes`
+    is the bytes handed over and not yet reclaimed. :meth:`later` runs a
+    callable on the same thread, before the next passes.
+    """
+
+    def __init__(self):
+        self._cv = threading.Condition()
+        self._queue: dict[Path, list[tuple[Path, int]]] = {}
+        self._later: list = []
+        self._busy = False
+        self._thread: threading.Thread | None = None
+        self.backlog_bytes = 0
+
+    def hand_over(self, reclaim_dir: Path, files: list[tuple[Path, int]]) -> None:
+        """Queue ``reclaim_dir`` for a pass; ``files`` are (path, bytes) just
+        detached into it."""
+        with self._cv:
+            self._queue.setdefault(reclaim_dir, []).extend(files)
+            self.backlog_bytes += sum(n for _, n in files)
+            self._wake()
+
+    def later(self, fn) -> None:
+        """Run ``fn()`` on the reclaimer thread."""
+        with self._cv:
+            self._later.append(fn)
+            self._wake()
+
+    def _wake(self) -> None:
+        if self._thread is None or not self._thread.is_alive():
+            self._thread = threading.Thread(target=self._run, name="cas-reclaim", daemon=True)
+            self._thread.start()
+        self._cv.notify()
+
+    def drain(self, timeout: float | None = None) -> bool:
+        """Wait until all queued work is done; False on timeout."""
+        with self._cv:
+            return self._cv.wait_for(
+                lambda: not self._queue and not self._later and not self._busy, timeout)
+
+    def _run(self) -> None:
+        while True:
+            with self._cv:
+                self._cv.wait_for(lambda: self._queue or self._later)
+                fns, self._later = self._later, []
+                work, self._queue = self._queue, {}
+                self._busy = True
+            for fn in fns:
+                try:
+                    fn()
+                except Exception:  # the thread serves every store of the process
+                    logger.exception("background GC step %r failed", fn)
+            for reclaim_dir, files in work.items():
+                try:
+                    self._pass(reclaim_dir, {p.name: n for p, n in files})
+                except Exception:
+                    logger.exception("reclaim of %s failed", reclaim_dir)
+                finally:
+                    with self._cv:
+                        self.backlog_bytes -= sum(n for _, n in files)
+            with self._cv:
+                self._busy = False
+                self._cv.notify_all()
+
+    def _pass(self, reclaim_dir: Path, sizes: dict[str, int]) -> None:
+        objects = nbytes = 0
+        with span("jobstore.reclaim"):
+            try:
+                entries = list(os.scandir(reclaim_dir))
+            except FileNotFoundError:  # the store itself was removed
+                entries = []
+            for entry in entries:
+                size = sizes.get(entry.name)
+                try:
+                    if size is None:
+                        size = entry.stat().st_size
+                    faults.fire("cas.gc.mid_sweep")
+                    os.unlink(entry.path)
+                except FileNotFoundError:  # another process's reclaimer got it
+                    continue
+                objects += 1
+                nbytes += size
+            annotate(objects=objects, bytes=nbytes)
+
+
+RECLAIMER = Reclaimer()
 
 
 class _StoreLock:

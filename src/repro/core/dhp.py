@@ -49,6 +49,7 @@ from typing import Any
 import jax
 
 from repro.chaos import faults
+from repro.checkpoint.cas import RECLAIMER
 from repro.checkpoint.serializer import SaveOptions
 from repro.core.cmi import mesh_resharding_resolver, restore_cmi, save_cmi, snapshot_to_host
 from repro.core.delta import DeltaPolicy, DeltaTracker
@@ -403,6 +404,7 @@ class DHP:
         return name
 
     def _do_publish_ckpt(self, job_id, name, state, step, meta, opts) -> None:
+        backlog = RECLAIMER.backlog_bytes  # detached by earlier sweeps, not yet unlinked
         faults.fire("publish.before_save")
         save_cmi(
             self.jobstore.cmi_root(job_id), name, state, step=step,
@@ -411,7 +413,7 @@ class DHP:
         # chaos point: the CMI is committed but the job record does not name
         # it yet — a kill here must leave the PREVIOUS publish authoritative
         faults.fire("publish.before_record")
-        with span("jobstore.record"):
+        with span("jobstore.record", reclaim_backlog_bytes=backlog):
             self.jobstore.svc_publish_job(
                 job_id, STATUS_CKPT, cmi=name, step=step,
                 keep_last=self.delta.policy.keep_last,

@@ -26,6 +26,7 @@ Storage is a directory tree with atomic JSON writes (tmp + rename) and
 from __future__ import annotations
 
 import fcntl
+import functools
 import json
 import os
 import shutil
@@ -37,8 +38,14 @@ from typing import Any
 
 from repro.chaos import faults
 from repro.checkpoint.atomic import gc_orphans, is_committed, list_committed
-from repro.checkpoint.cas import ObjectStore, referenced_digests
+from repro.checkpoint.cas import (
+    RECLAIMER,
+    ObjectStore,
+    referenced_digests,
+    referenced_objects,
+)
 from repro.checkpoint.serializer import load_manifest
+from repro.spans import span
 from repro.utils import logger
 
 STATUS_NEW = "new"
@@ -101,6 +108,7 @@ class JobStore:
     def __init__(self, root: str | os.PathLike):
         self.root = Path(root)
         (self.root / "jobs").mkdir(parents=True, exist_ok=True)
+        self._walked: set[str] = set()  # jobs whose whole object tree a sweep has walked
 
     # -- paths ------------------------------------------------------------
     def job_dir(self, job_id: str) -> Path:
@@ -245,6 +253,7 @@ class JobStore:
                 job.status = STATUS_FINISHED
                 job.lease_owner = None
                 self._update(job, f"publish:finished:{product}")
+                self._walked.discard(job_id)  # no GC runs for it again
         if status == STATUS_CKPT:
             self.gc_cmis(job_id, keep_last=keep_last)
         return job
@@ -312,7 +321,10 @@ class JobStore:
         dirs at all: their chunks live in the shared object tree, so after
         dropping manifest dirs the ``keep_last`` policy becomes a
         manifest-root mark-and-sweep over the refcounted objects
-        (:meth:`_gc_objects`).
+        (:meth:`_gc_objects`), over the objects of the manifests dropped
+        here. The sweep only detaches them into the job's ``.reclaim/``
+        directory; their unlinks run later on the process's reclaimer
+        thread (:meth:`drain_reclaim`).
         """
         cmis = self.list_cmis(job_id)
         keep = set(cmis[-keep_last:]) if keep_last > 0 else set()
@@ -331,36 +343,60 @@ class JobStore:
                 keep.add(man.parent)
                 frontier.append(man.parent)
         removed = []
+        dropped: dict[str, int] = {}  # their objects: the sweep's candidates
         for name in cmis:
             if name not in keep:
+                try:
+                    dropped |= referenced_objects(load_manifest(self.cmi_root(job_id), name))
+                except Exception:
+                    pass  # its objects, if any, wait for a full sweep
                 shutil.rmtree(self.job_dir(job_id) / name, ignore_errors=True)
                 removed.append(name)
         gc_orphans(self.job_dir(job_id))
-        swept = self._gc_objects(job_id)
+        swept = self._gc_objects(job_id, dropped)
         if removed or swept:
             logger.debug("gc job %s: removed %s, swept %d object(s)",
                          job_id, removed, len(swept))
         return removed
 
-    def _gc_objects(self, job_id: str) -> list[str]:
+    def _gc_objects(self, job_id: str, candidates: dict[str, int] | None = None) -> list[str]:
         """Mark-and-sweep the job's content-addressed object tree.
 
         Mark: every digest referenced by any *committed* manifest still in
         the job dir (surviving CMIs and products are the GC roots). Sweep:
-        unlink everything else. The exclusive fcntl guard mutually excludes
-        in-flight publishers (which hold the shared guard across object
-        writes + manifest commit), so the mark set can never miss a
-        manifest that commits mid-sweep.
+        detach the unmarked ``candidates`` into ``<job dir>/.reclaim/``,
+        from where the reclaimer thread unlinks them (:meth:`ObjectStore.sweep`).
+        Without candidates the sweep walks the whole tree, for what no
+        dropped manifest names: objects and tmp files of killed publishers.
+        That walk runs on the reclaimer thread, once per job for this
+        instance, at its first GC here. The exclusive fcntl guard mutually
+        excludes in-flight publishers (which hold the shared guard across
+        object writes + manifest commit), so the mark set can never miss a
+        manifest that commits mid-sweep, and a publisher that dedups
+        against ``store.has`` never meets an object that is about to leave
+        the tree.
         """
         root = self.cmi_root(job_id)
         store = ObjectStore(root)
         if not store.dir.is_dir():
             return []
-        with store.sweep_guard():
+        if job_id not in self._walked:
+            self._walked.add(job_id)
+            RECLAIMER.later(functools.partial(self._gc_objects, job_id))
+        if candidates is not None and not candidates:
+            return []
+        with span("jobstore.sweep", walk=candidates is None), store.sweep_guard():
             marked: set[str] = set()
             for name in list_committed(root):
                 try:
                     marked |= referenced_digests(load_manifest(root, name))
                 except Exception:
                     return []  # unreadable root: abort, sweep nothing
-            return store.sweep(marked)
+            return store.sweep(marked, candidates)
+
+    @staticmethod
+    def drain_reclaim(timeout: float | None = None) -> bool:
+        """Wait until every object the process's sweeps detached is
+        unlinked; False on timeout. Publishes, flushes and resumes never
+        wait for it."""
+        return RECLAIMER.drain(timeout)

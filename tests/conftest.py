@@ -1,6 +1,7 @@
 import os
 import subprocess
 import sys
+import threading
 from pathlib import Path
 
 import pytest
@@ -37,3 +38,23 @@ def run_python(code: str, *, devices: int = 1, timeout: int = 300) -> str:
 @pytest.fixture
 def subproc():
     return run_python
+
+
+@pytest.fixture
+def held_reclaimer(monkeypatch):
+    """Hold the process's reclaimer before each pass until the test sets the
+    returned event (set again at teardown, so a failing test frees it)."""
+    from repro.checkpoint.cas import Reclaimer
+
+    gate = threading.Event()
+    real = Reclaimer._pass
+
+    def held(self, *args):
+        gate.wait(timeout=60)
+        real(self, *args)
+
+    monkeypatch.setattr(Reclaimer, "_pass", held)
+    try:
+        yield gate
+    finally:
+        gate.set()

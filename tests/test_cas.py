@@ -227,3 +227,44 @@ def test_fsck_strict_flags_orphans(tmp_path):
         removed = store.sweep(referenced_digests(man))
     assert removed == [orphan]
     assert fsck_main([str(tmp_path), "-q", "--strict"]) == 0
+
+
+def test_concurrent_sweeps_reclaim_every_detached_byte(tmp_path):
+    """Many threads detach into their own stores while the one reclaimer
+    unlinks: every file is reclaimed once and the backlog returns to 0."""
+    import sys
+    import threading
+
+    from repro.checkpoint.cas import RECLAIMER
+    from repro.utils import content_hash
+
+    def worker(k, out):
+        store = ObjectStore(tmp_path / f"s{k}")
+        for r in range(5):
+            blobs = [f"{k}-{r}-{i}".encode() * 64 for i in range(6)]
+            digests = [content_hash(b) for b in blobs]
+            with store.publish_guard():
+                for d, b in zip(digests, blobs):
+                    store.put(d, b)
+            with store.sweep_guard():
+                out[k] += len(store.sweep(set(digests[:2])))
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        out = [0] * 8
+        threads = [threading.Thread(target=worker, args=(k, out)) for k in range(8)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(interval)
+    assert RECLAIMER.drain(timeout=60)
+    # each round keeps its first 2 objects: 4 of this round + the previous round's 2 go
+    assert out == [4 + 4 * 6] * 8
+    assert RECLAIMER.backlog_bytes == 0
+    for k in range(8):
+        assert list((tmp_path / f"s{k}" / ".reclaim").iterdir()) == []
+        assert len(ObjectStore(tmp_path / f"s{k}").digests()) == 2

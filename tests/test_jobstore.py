@@ -144,3 +144,123 @@ def test_heartbeat_thread_keeps_lease_alive(tmp_path):
             time.sleep(0.1)
     finally:
         stop.set()
+
+
+# ---------------------------------------------------------------------------
+# keep_last sweep: detach under the store lock, reclaim on a background thread
+# ---------------------------------------------------------------------------
+
+CAS_CHUNK = 4096  # one float64 row of 512 per chunk
+
+
+def _cas_publish(store, job_id, name, w, parent=None, step=0):
+    save_checkpoint(store.cmi_root(job_id), name, {"w": w},
+                    options=SaveOptions(chunk_bytes=CAS_CHUNK, cas=True, parent=parent))
+    store.svc_publish_job(job_id, STATUS_CKPT, cmi=name, step=step, keep_last=2)
+
+
+def _kept_refs(root):
+    from repro.checkpoint.atomic import list_committed
+    from repro.checkpoint.cas import referenced_digests
+    from repro.checkpoint.serializer import load_manifest
+
+    out = set()
+    for p in list_committed(root):
+        out |= referenced_digests(load_manifest(root, p.name))
+    return out
+
+
+def _publish_series(store, job_id, states):
+    parent = None
+    for i, w in enumerate(states):
+        name = f"cmi-{i:04d}"
+        _cas_publish(store, job_id, name, w, parent=parent, step=i)
+        parent = name
+    return parent
+
+
+def test_sweep_leaves_exactly_the_kept_objects_without_a_drain(tmp_path, held_reclaimer):
+    from repro.checkpoint.cas import ObjectStore
+
+    store = JobStore(tmp_path)
+    j = store.create_job({}).job_id
+    rng = np.random.default_rng(11)
+    _publish_series(store, j, [rng.standard_normal((8, 512)) for _ in range(4)])
+    root = store.cmi_root(j)
+    # the reclaimer is held: nothing detached has been unlinked yet
+    assert set(ObjectStore(root).digests()) == _kept_refs(root)
+    assert len(list((root / ".reclaim").iterdir())) == 2 * 8
+
+
+def test_drain_reclaim_empties_the_reclaim_dir(tmp_path):
+    from repro.checkpoint.cas import RECLAIMER
+
+    store = JobStore(tmp_path)
+    j = store.create_job({}).job_id
+    rng = np.random.default_rng(12)
+    _publish_series(store, j, [rng.standard_normal((8, 512)) for _ in range(4)])
+    assert store.drain_reclaim(timeout=60)
+    assert list((store.cmi_root(j) / ".reclaim").iterdir()) == []
+    assert RECLAIMER.backlog_bytes == 0
+
+
+def test_reclaim_dir_left_by_a_killed_process_is_emptied_by_the_next_sweep(tmp_path):
+    from repro.checkpoint.cas import ObjectStore
+    from repro.checkpoint.fsck import fsck_store
+    from repro.checkpoint.fsck import main as fsck_main
+    from repro.utils import content_hash
+
+    store = JobStore(tmp_path)
+    j = store.create_job({}).job_id
+    rng = np.random.default_rng(13)
+    last = _publish_series(store, j, [rng.standard_normal((8, 512)) for _ in range(3)])
+    assert store.drain_reclaim(timeout=60)
+    root = store.cmi_root(j)
+    # a process killed between detach and reclaim leaves its files behind
+    for k in range(3):
+        (root / ".reclaim" / f"{k:064x}.4242.{k}").write_bytes(b"x" * CAS_CHUNK)
+    report = fsck_store(root)
+    assert report.clean and not report.orphans, report.summary()
+    assert fsck_main([str(root), "-q", "--strict"]) == 0
+    # ... and a killed publisher an object no manifest names, and a tmp file
+    objects = ObjectStore(root)
+    orphan = content_hash(b"orphaned")
+    objects.put(orphan, b"orphaned")
+    (objects.path(orphan).parent / ".tmp-0123-4242-1").write_bytes(b"torn")
+    assert len(fsck_store(root).orphans) == 2
+    # the next process's JobStore: its first GC of the job walks the tree
+    again = JobStore(tmp_path)
+    _cas_publish(again, j, "cmi-0009", rng.standard_normal((8, 512)), parent=last, step=9)
+    assert again.drain_reclaim(timeout=60)
+    assert list((root / ".reclaim").iterdir()) == []
+    assert not objects.has(orphan) and not objects.tmp_files()
+    assert fsck_main([str(root), "-q", "--strict"]) == 0
+
+
+def test_digest_detached_twice_before_reclaim_never_collides(tmp_path, held_reclaimer):
+    from repro.checkpoint import load_checkpoint
+    from repro.checkpoint.cas import ObjectStore, referenced_digests
+    from repro.checkpoint.fsck import main as fsck_main
+    from repro.checkpoint.serializer import load_manifest
+
+    store = JobStore(tmp_path)
+    j = store.create_job({}).job_id
+    root = store.cmi_root(j)
+    rng = np.random.default_rng(14)
+    a, b, c = (rng.standard_normal((8, 512)) for _ in range(3))
+    _cas_publish(store, j, "cmi-0000", a)
+    a_digests = referenced_digests(load_manifest(root, "cmi-0000"))
+    # A's objects: detached by the third publish, written again by the
+    # fourth, detached again by the sixth, the reclaimer held throughout
+    for i, w in enumerate([b, c, a, b, c], start=1):
+        _cas_publish(store, j, f"cmi-{i:04d}", w, parent=f"cmi-{i - 1:04d}", step=i)
+    names = [f.name for f in (root / ".reclaim").iterdir()]
+    assert len(a_digests) == 8 and len(names) == 4 * 8
+    assert all(sum(n.split(".")[0] == d for n in names) == 2 for d in a_digests)
+    assert set(ObjectStore(root).digests()) == _kept_refs(root)
+    got, _ = load_checkpoint(root, "cmi-0005")
+    assert got["w"].tobytes() == c.tobytes()
+    held_reclaimer.set()
+    assert store.drain_reclaim(timeout=60)
+    assert list((root / ".reclaim").iterdir()) == []
+    assert fsck_main([str(root), "-q", "--strict"]) == 0

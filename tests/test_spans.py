@@ -234,3 +234,39 @@ def test_admit_prefill_s_is_the_spans_duration():
     (pre,) = [r for r in recs if r.name == "engine.prefill"]
     assert res["prefill_s"] == pre.duration
     assert [r.name for r in recs].count("engine.decode") == 1
+
+
+def test_sweep_and_reclaim_spans_count_the_detached_objects(dhp):
+    dhp, store = dhp
+    job = store.create_job({}).job_id
+    for k in range(2):
+        publish(dhp, job, small_state(float(k)), k)
+    assert store.drain_reclaim(timeout=60)  # no earlier background work lands in ours
+    _, pub, recs = publish(dhp, job, small_state(2.0), 2)  # drops the first CMI's objects
+    assert store.drain_reclaim(timeout=60)
+    (rec,) = [r for r in children(recs, pub) if r.name == "jobstore.record"]
+    assert rec.attrs["reclaim_backlog_bytes"] == 0
+    (sweep,) = [r for r in since(rec.start) if r.name == "jobstore.sweep"]
+    assert sweep.parent == rec.id and sweep.attrs["walk"] is False
+    assert sweep.attrs["detached_objects"] > 0 and sweep.attrs["detached_bytes"] > 0
+    (reclaim,) = [r for r in since(sweep.start) if r.name == "jobstore.reclaim"]
+    assert reclaim.thread != sweep.thread and reclaim.parent is None
+    assert reclaim.attrs == {"objects": sweep.attrs["detached_objects"],
+                             "bytes": sweep.attrs["detached_bytes"]}
+
+
+def test_flush_and_restart_do_not_wait_for_the_reclaimer(dhp, held_reclaimer):
+    dhp, store = dhp
+    job = store.create_job({}).job_id
+    for k in range(4):
+        publish(dhp, job, small_state(float(k)), k)
+    dhp.flush(timeout=30)
+    state, step = dhp.restart(job)
+    assert step == 3 and np.array_equal(np.asarray(state["w"]), np.asarray(small_state(3.0)["w"]))
+    # every detached object is still waiting for the held reclaimer
+    assert list((store.cmi_root(job) / ".reclaim").iterdir())
+    _, pub, recs = publish(dhp, job, small_state(4.0), 4)
+    (rec,) = [r for r in children(recs, pub) if r.name == "jobstore.record"]
+    assert rec.attrs["reclaim_backlog_bytes"] > 0
+    held_reclaimer.set()
+    assert store.drain_reclaim(timeout=60)
