@@ -1,5 +1,6 @@
-"""What every cell shares: finding its files by name, the chip check, seeds,
-host spans, the metric readers and the result line.
+"""What every cell shares: finding its files by name (the configuration's
+reference among them), the chip check, seeds, host spans, the
+metric readers and the result line.
 
 Nothing here imports JAX at module level: the chip check must run before
 any backend starts.
@@ -102,18 +103,44 @@ def bytes_written() -> int | None:
         return None
 
 
+# Where ``reference(c)`` looks for ``<c["reference"]>.py`` (a test points it elsewhere).
+REFERENCE_PACKAGE = "benchmarks.chip.reference"
+# What every run calls of a reference module; each kind adds its own (``kinds/<kind>.REFERENCE``).
+REFERENCE_NEEDS = ("make_params", "arch_fields")
+
+
+def reference(c: dict):
+    """The plain reference module the configuration names (``reference/__init__.py``
+    states what it keeps). It also counts the operations and bytes of the
+    architecture's steps: the metric readers get it as ``run.flops``."""
+    return importlib.import_module(f"{REFERENCE_PACKAGE}.{c['reference']}")
+
+
+def kind_for(cell: dict):
+    """The kind module that runs the cell's traffic (``kinds/<kind>.py``).
+
+    Before any set-up, it refuses in one line a configuration whose reference
+    module is missing or lacks what the run will call of it.
+    """
+    c = config(cell["config"])
+    kind = importlib.import_module(f"benchmarks.chip.kinds.{traffic(cell['traffic'])['kind']}")
+    refuse = f"configuration {c['name']!r}:"
+    name = f"{REFERENCE_PACKAGE}.{c.get('reference')}"
+    if "reference" not in c or importlib.util.find_spec(name) is None:
+        raise SystemExit(f"{refuse} no reference module {name}")
+    missing = [f for f in REFERENCE_NEEDS + kind.REFERENCE if not hasattr(reference(c), f)]
+    if missing:
+        raise SystemExit(f"{refuse} reference {name} lacks {', '.join(missing)}")
+    return kind
+
+
 def program_config(c: dict):
     """The program's ``ArchConfig`` for a configuration file: the registry
-    entry of ``program.arch`` with every size the file states."""
+    entry of ``program.arch`` with every size the file states, mapped by the
+    configuration's reference (``arch_fields``)."""
     from repro.configs import get_config
 
-    return get_config(c["program"]["arch"]).with_(
-        n_layers=c["num_hidden_layers"], d_model=c["hidden_size"], d_ff=c["intermediate_size"],
-        n_heads=c["num_attention_heads"], n_kv_heads=c["num_key_value_heads"],
-        head_dim=c["head_dim"], vocab=c["vocab_size"], rope_theta=float(c["rope_theta"]),
-        norm_eps=c["rms_norm_eps"], tie_embeddings=c["tie_word_embeddings"],
-        attn_bias=c["attention_bias"], dtype=c["torch_dtype"],
-    )
+    return get_config(c["program"]["arch"]).with_(**reference(c).arch_fields(c))
 
 
 class Spans:

@@ -34,6 +34,8 @@ import numpy as np
 from benchmarks.chip import harness
 
 HOSTS = ("s0", "s1")
+# What this kind calls of the configuration's reference, beyond what every run does.
+REFERENCE = ("served_gaps", "decode_flops", "decode_bytes")
 
 
 def _counts(shares: dict, n: int) -> dict:
@@ -84,7 +86,6 @@ def build_engine(c: dict, cfg, seed: int):
     """
     import jax
 
-    from benchmarks.chip.reference import dense_gqa as ref
     from repro.models import Model
     from repro.serve.engine import make_engine
 
@@ -97,7 +98,7 @@ def build_engine(c: dict, cfg, seed: int):
     gc.collect()
     engine.cfg, engine.model = cfg, Model(cfg)
     engine._decode_fn = jax.jit(engine.model.decode)
-    engine.params = ref.make_params(c, seed, c["torch_dtype"])
+    engine.params = harness.reference(c).make_params(c, seed, c["torch_dtype"])
     _check_layout(engine.params, cfg)
     return engine
 
@@ -291,9 +292,8 @@ def check(h, out: dict, fmt=None) -> dict:
     program (the control): ``served_gap`` is then the widest gap of the
     tokens it would have put first at the same positions.
     """
-    from benchmarks.chip.reference import dense_gqa as ref
-
     c, tr = h.config, h.traffic
+    ref = harness.reference(c)
     reqs = list(out["loop"].req.values())
     unfinished = sum(1 for r in reqs if r["finished_t"] is None)
     torn = sum(1 for r in reqs if r["resumed_ok"] is False)

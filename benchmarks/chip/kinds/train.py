@@ -36,6 +36,8 @@ import numpy as np
 from benchmarks.chip import harness
 
 CAPTURE_STEPS = (1, 3)
+# What this kind calls of the configuration's reference, beyond what every run does.
+REFERENCE = ("flat", "train_steps", "train_step_flops")
 
 
 class WindowClosed(Exception):
@@ -76,8 +78,6 @@ def initial_state(c: dict, seed: int):
     import jax
     import jax.numpy as jnp
 
-    from benchmarks.chip.reference import dense_gqa as ref
-
     @jax.jit
     def around(params, s):
         f32 = jax.tree_util.tree_map(lambda p: p.astype(jnp.float32), params)
@@ -91,7 +91,7 @@ def initial_state(c: dict, seed: int):
             "data": {"data_step": jnp.zeros((), jnp.int32), "seed": s.astype(jnp.int32)},
         }
 
-    return around(ref.make_params(c, seed, c["torch_dtype"]), jnp.uint32(seed))
+    return around(harness.reference(c).make_params(c, seed, c["torch_dtype"]), jnp.uint32(seed))
 
 
 def _check_layout(state, cfg) -> None:
@@ -217,10 +217,10 @@ def check(h, out: dict, fmt=None) -> dict:
     """
     import jax
 
-    from benchmarks.chip.reference import dense_gqa as ref
     from repro.core.cmi import restore_cmi
 
     c, tr, opt = h.config, h.traffic, h.config["optimizer"]
+    ref = harness.reference(c)
     seed = out["seed31"]
     vocab, seq, batch = c["vocab_size"], tr["seq_len"], tr["batch"]
     batches = [token_batch(seed, k, batch, seq, vocab) for k in range(3)]

@@ -17,7 +17,6 @@ with that fault planted (``faults.py``): its readings are upper ones.
 from __future__ import annotations
 
 import argparse
-import importlib
 import json
 import shutil
 import sys
@@ -42,6 +41,7 @@ def main(argv=None) -> int:
     from benchmarks.chip.run import Context, judge
 
     cell = harness.workload(args.workload)
+    kind = harness.kind_for(cell)
     harness.add_program_to_path()
     harness.require_chips(cell["chips"])
     from repro.utils import enable_compile_cache
@@ -56,7 +56,6 @@ def main(argv=None) -> int:
         work = Path(tempfile.mkdtemp(prefix="chipbench-readings-"))
         try:
             h = Context(cell, seed, args.seconds, 0, work)
-            kind = importlib.import_module(f"benchmarks.chip.kinds.{h.traffic['kind']}")
             out = kind.run(h)
             numbers = kind.check(h, out)
             row = {"seed": seed, "fault": args.fault, "program": numbers,

@@ -32,7 +32,21 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+# The operation and byte counts of this architecture's steps, for the metric readers.
+from benchmarks.chip.flops import decode_bytes, decode_flops, train_step_flops  # noqa: F401
+
 FP8_MAX = 448.0  # largest finite float8_e4m3fn
+
+
+def arch_fields(c: dict) -> dict:
+    """The program's ``ArchConfig`` fields that the configuration file sets."""
+    return dict(
+        n_layers=c["num_hidden_layers"], d_model=c["hidden_size"], d_ff=c["intermediate_size"],
+        n_heads=c["num_attention_heads"], n_kv_heads=c["num_key_value_heads"],
+        head_dim=c["head_dim"], vocab=c["vocab_size"], rope_theta=float(c["rope_theta"]),
+        norm_eps=c["rms_norm_eps"], tie_embeddings=c["tie_word_embeddings"],
+        attn_bias=c["attention_bias"], dtype=c["torch_dtype"],
+    )
 
 
 def dims(c: dict) -> dict:

@@ -5,10 +5,12 @@
 The cell is looked up by name in ``BENCHMARK.json``; its configuration,
 traffic mix and metrics are files under ``benchmarks/chip`` found by name
 (``configs/``, ``traffic/``, ``metrics/``). The traffic file names the
-kind (``kinds/<kind>.py``) that runs it. Set-up (backend, weights,
-every shape the traffic uses) counts as ``setup_s``; then the window runs
-for ``--seconds``; after it, with the program's state freed, the plain
-reference decides ``correct``.
+kind (``kinds/<kind>.py``) that runs it; the configuration names its plain
+reference (``reference/<name>.py``), which maps the file onto the
+program's configuration and counts a step's operations and bytes. A
+reference that lacks what the run calls is refused before set-up. Set-up (backend, weights, every shape the traffic uses)
+counts as ``setup_s``; then the window runs for ``--seconds``; after it,
+with the program's state freed, the plain reference decides ``correct``.
 
 With ``--trace 0`` the result line carries the cell's end-to-end metrics,
 with ``--trace 1`` its per-layer ones read from the profiler's trace of the
@@ -26,7 +28,6 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
-import importlib  # noqa: E402
 import json  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
@@ -34,7 +35,7 @@ import tempfile  # noqa: E402
 from pathlib import Path  # noqa: E402
 from types import SimpleNamespace  # noqa: E402
 
-from benchmarks.chip import flops, harness  # noqa: E402
+from benchmarks.chip import harness  # noqa: E402
 from benchmarks.chip import trace as tracing  # noqa: E402
 
 
@@ -71,7 +72,8 @@ def read_metrics(h, out, device, red) -> dict:
     run = SimpleNamespace(
         out=out, config=h.config, traffic=h.traffic, cell=h.cell["name"],
         t0=t0, t1=t1, window_s=t1 - t0, setup_s=out["setup_end"] - T_START,
-        trace=red, peaks=harness.peaks(device["kind"]), flops=flops, spans=h.spans.records,
+        trace=red, peaks=harness.peaks(device["kind"]), flops=harness.reference(h.config),
+        spans=h.spans.records,
     )
     metrics = {}
     for m in harness.metrics_for(h.cell["name"], bool(h.trace)):
@@ -99,6 +101,7 @@ def main(argv: list[str] | None = None) -> int:
     args = ap.parse_args(argv)
 
     cell = harness.workload(args.workload)
+    kind = harness.kind_for(cell)
     harness.add_program_to_path()
     device = harness.require_chips(cell["chips"])
     from repro.utils import enable_compile_cache
@@ -107,7 +110,6 @@ def main(argv: list[str] | None = None) -> int:
     work = Path(tempfile.mkdtemp(prefix="chipbench-"))
     try:
         h = Context(cell, args.seed, args.seconds, args.trace, work)
-        kind = importlib.import_module(f"benchmarks.chip.kinds.{h.traffic['kind']}")
         out = kind.run(h)
         h.on_window_closed()
         red = tracing.reduce(tracing.load(h.trace_dir)) if args.trace else None

@@ -14,7 +14,7 @@ import time
 from pathlib import Path
 from types import SimpleNamespace
 
-from benchmarks.chip import flops, harness
+from benchmarks.chip import harness
 
 harness.add_program_to_path()
 
@@ -81,5 +81,5 @@ def run_view(h, out) -> SimpleNamespace:
     t0, t1 = out["window"]
     return SimpleNamespace(out=out, config=h.config, traffic=h.traffic, t0=t0, t1=t1,
                            window_s=t1 - t0, setup_s=out["setup_end"] - time.perf_counter() + 1.0,
-                           trace=None, peaks=harness.peaks("TPU v5 lite"), flops=flops,
-                           spans=h.spans.records)
+                           trace=None, peaks=harness.peaks("TPU v5 lite"),
+                           flops=harness.reference(h.config), spans=h.spans.records)
