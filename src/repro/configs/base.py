@@ -53,7 +53,11 @@ class ArchConfig:
     moe_d_ff: int = 0  # per-expert hidden (fine-grained); 0 -> d_ff
     first_dense_layers: int = 0
     router_type: str = "softmax"  # softmax | sigmoid (deepseek aux-free)
-    capacity_factor: float = 1.25
+    routed_scale: float = 1.0  # routed_scaling_factor: the routed experts' gates times this
+    # expert parallelism: ep_size ranks share each MoE layer's experts; this
+    # one (ep_rank) holds ids [ep_rank * held, (ep_rank + 1) * held)
+    ep_size: int = 1
+    ep_rank: int = 0
 
     # MLA (deepseek)
     mla: bool = False
@@ -97,6 +101,11 @@ class ArchConfig:
         return self.moe_d_ff or self.d_ff
 
     @property
+    def held_experts(self) -> int:
+        """Routed experts of each MoE layer held here (all of them at ``ep_size`` 1)."""
+        return self.n_experts // self.ep_size
+
+    @property
     def sub_quadratic(self) -> bool:
         """Can this arch run long_500k? (SSM state and/or windowed attention)"""
         return self.mlstm or (self.ssm and self.window > 0)
@@ -114,9 +123,11 @@ class ArchConfig:
         n_emb = v * e * (1 if self.tie_embeddings else 2)
         per_layer = 0
         if self.mla:
-            per_layer += e * self.q_lora_rank + self.q_lora_rank * self.n_heads * (
-                self.qk_nope_dim + self.qk_rope_dim
-            )
+            q_dim = self.n_heads * (self.qk_nope_dim + self.qk_rope_dim)
+            if self.q_lora_rank:
+                per_layer += e * self.q_lora_rank + self.q_lora_rank * q_dim
+            else:
+                per_layer += e * q_dim
             per_layer += e * (self.kv_lora_rank + self.qk_rope_dim)
             per_layer += self.kv_lora_rank * self.n_heads * (self.qk_nope_dim + self.v_head_dim)
             per_layer += self.n_heads * self.v_head_dim * e
@@ -137,7 +148,7 @@ class ArchConfig:
         moe_ffn = 0
         if self.moe:
             f = self.resolved_moe_d_ff
-            moe_ffn = 3 * e * f * (self.n_experts + self.n_shared_experts) + e * self.n_experts
+            moe_ffn = 3 * e * f * (self.held_experts + self.n_shared_experts) + e * self.n_experts
         total = n_emb + self.n_layers * per_layer
         total += n_dense_layers * per_dense_ffn + n_moe_layers * moe_ffn
         if self.encdec:
@@ -146,11 +157,13 @@ class ArchConfig:
         return int(total)
 
     def active_param_count(self) -> int:
-        """Active params per token (MoE: top_k + shared experts only)."""
+        """Active params per token (MoE: the shared experts and the routed
+        ones held here that a token routes to, top_k * held / n_experts of
+        them on average)."""
         if not self.moe:
             return self.param_count()
         f = self.resolved_moe_d_ff
         e = self.d_model
         n_moe_layers = self.n_layers - self.first_dense_layers
-        inactive = n_moe_layers * 3 * e * f * (self.n_experts - self.top_k)
-        return int(self.param_count() - inactive)
+        idle = self.held_experts - self.top_k * self.held_experts / self.n_experts
+        return int(self.param_count() - n_moe_layers * 3 * e * f * idle)

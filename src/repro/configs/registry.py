@@ -13,6 +13,7 @@ _MODULES: dict[str, str] = {
     "command-r-plus-104b": "repro.configs.command_r_plus_104b",
     "granite-moe-1b-a400m": "repro.configs.granite_moe_1b_a400m",
     "deepseek-v3-671b": "repro.configs.deepseek_v3_671b",
+    "moonlight-16b-a3b": "repro.configs.moonlight_16b_a3b",
     "hymba-1.5b": "repro.configs.hymba_1_5b",
     "xlstm-1.3b": "repro.configs.xlstm_1_3b",
     "internvl2-76b": "repro.configs.internvl2_76b",

@@ -1,16 +1,13 @@
 """Trace-time sharding-constraint context.
 
 Perf iterations steer GSPMD with `with_sharding_constraint` at a few
-well-chosen points (residual stream, MoE dispatch buffer). The model code
+well-chosen points (the residual stream). The model code
 stays mesh-agnostic: it calls ``constrain(x, kind)`` and the step builder
 installs concrete NamedShardings for each kind before tracing.
 
 Kinds:
   resid    — (B, S, E) residual stream between layers
              (seq-parallel hillclimb: P(batch, "model", None))
-  moe_buf  — (G, X, C, E) expert dispatch buffer
-             (EP hillclimb: P(None, ("data","model"), None, None) keeps the
-             grouped GEMM expert-local so tokens move, not 7.5 GB weights)
 """
 
 from __future__ import annotations

@@ -158,54 +158,29 @@ def make_train_step(
     peak_lr: float = 3e-4,
     warmup: int = 100,
     total_steps: int = 10_000,
-    n_route_groups: int = 0,
     seq_shard: bool = False,
-    moe_buf_shard: bool = False,
 ):
     """Returns (train_step, state_shardings, batch_shardings).
 
     ``train_step(state, batch) -> (state, metrics)``; donate state.
-    MoE route groups default to the data-parallel degree so routing is
-    shard-local (DESIGN.md §5).
 
-    Perf knobs (EXPERIMENTS.md §Perf):
+    Perf knob (EXPERIMENTS.md §Perf):
       seq_shard     — sequence-parallel residual stream: activations between
                       layers carry P(batch, "model", None); GSPMD inserts the
                       Megatron-SP all-gather/reduce-scatter transitions.
-      moe_buf_shard — constrain the MoE dispatch buffer expert-sharded so the
-                      grouped GEMM is local (token a2a, not weight gathers).
     """
     from repro.distributed.ctx import sharding_context
 
     model = Model(cfg)
-    if n_route_groups == 0:
-        sizes = dict(mesh.shape)
-        n_route_groups = 1
-        for a in batch_axes(mesh):
-            n_route_groups *= sizes[a]
-
     bax = batch_axes(mesh)
     bspec = tuple(bax) if len(bax) > 1 else (bax[0] if bax else None)
     constraints = {}
     if seq_shard:
         constraints["resid"] = NamedSharding(mesh, P(bspec, "model", None))
-    if moe_buf_shard and cfg.moe:
-        expert_axes = DEFAULT_RULES["experts"]
-        sizes = dict(mesh.shape)
-        for cand in expert_axes:
-            cand = tuple(a for a in cand if a in sizes)
-            import numpy as _np
-
-            if cand and cfg.n_experts % int(_np.prod([sizes[a] for a in cand])) == 0:
-                constraints["moe_buf"] = NamedSharding(
-                    mesh, P(cand if len(cand) > 1 else cand[0], None, None)
-                )
-                break
-
     def train_step(state, batch):
         with sharding_context(constraints):
             def loss_fn(p):
-                return model.loss(p, batch, n_groups=n_route_groups)
+                return model.loss(p, batch)
 
             # NOTE gradient compression: params are bf16, so grads and their
             # data-parallel all-reduce are already bf16 on the wire (verified

@@ -59,9 +59,12 @@ def init_mla(key, cfg: ArchConfig, n_layers: int):
     dt = pdtype(cfg)
     ks = jax.random.split(key, 6)
     p, a = {}, {}
-    p["wq_a"], a["wq_a"] = init_dense(ks[0], (n_layers, e, qr), ("layers", "embed", "q_lora"), dt)
-    p["q_ln"] = jnp.ones((n_layers, qr), dt); a["q_ln"] = ("layers", "q_lora")
-    p["wq_b"], a["wq_b"] = init_dense(ks[1], (n_layers, qr, h, nd + rd), ("layers", "q_lora", "heads", "head_dim"), dt)
+    if qr:
+        p["wq_a"], a["wq_a"] = init_dense(ks[0], (n_layers, e, qr), ("layers", "embed", "q_lora"), dt)
+        p["q_ln"] = jnp.ones((n_layers, qr), dt); a["q_ln"] = ("layers", "q_lora")
+        p["wq_b"], a["wq_b"] = init_dense(ks[1], (n_layers, qr, h, nd + rd), ("layers", "q_lora", "heads", "head_dim"), dt)
+    else:  # no query LoRA (q_lora_rank 0): a plain x Wq
+        p["wq"], a["wq"] = init_dense(ks[0], (n_layers, e, h, nd + rd), ("layers", "embed", "heads", "head_dim"), dt)
     p["wkv_a"], a["wkv_a"] = init_dense(ks[2], (n_layers, e, kvr + rd), ("layers", "embed", None), dt)
     p["kv_ln"] = jnp.ones((n_layers, kvr), dt); a["kv_ln"] = ("layers", None)
     p["wkv_b"], a["wkv_b"] = init_dense(ks[3], (n_layers, kvr, h, nd + vd), ("layers", None, "heads", "head_dim"), dt)
@@ -254,8 +257,11 @@ def gqa_decode(p, x, cache: dict, pos, cfg: ArchConfig, *, use_rope: bool = True
 
 def _mla_qkv(p, x, cfg: ArchConfig, positions):
     nd, rd = cfg.qk_nope_dim, cfg.qk_rope_dim
-    cq = rmsnorm(jnp.einsum("bse,eq->bsq", x, p["wq_a"]), p["q_ln"], cfg.norm_eps)
-    q = jnp.einsum("bsq,qhd->bshd", cq, p["wq_b"])  # (B,S,H,nd+rd)
+    if cfg.q_lora_rank:
+        cq = rmsnorm(jnp.einsum("bse,eq->bsq", x, p["wq_a"]), p["q_ln"], cfg.norm_eps)
+        q = jnp.einsum("bsq,qhd->bshd", cq, p["wq_b"])  # (B,S,H,nd+rd)
+    else:
+        q = jnp.einsum("bse,ehd->bshd", x, p["wq"])
     q_nope, q_rope = q[..., :nd], q[..., nd:]
     q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
     ckv_full = jnp.einsum("bse,ek->bsk", x, p["wkv_a"])

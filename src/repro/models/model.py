@@ -5,6 +5,7 @@
     loss = model.loss(params, batch)                      # train shapes
     hidden, caches = model.prefill(params, batch, s_max)   # prefill shapes
     logits, caches = model.decode(params, caches, tok, pos)# decode shapes
+    (..., counts) = model.prefill/decode(..., counts=True)  # + the FFNs' counts
     caches = model.init_cache(batch, s_ctx)                # zeros / specs
 
 ``input_specs(cfg, shape)`` produces the ShapeDtypeStruct stand-ins the
@@ -52,20 +53,23 @@ class Model:
         return x, labels
 
     # -- train --------------------------------------------------------------
-    def loss(self, params, batch, *, n_groups: int = 0) -> jax.Array:
+    def loss(self, params, batch) -> jax.Array:
         cfg = self.cfg
         if cfg.encdec:
             enc_out = encdec_mod.encode(params, batch["enc_frames"].astype(pdtype(cfg)), cfg)
             h = encdec_mod.decode_train(params, batch["tokens"], enc_out, cfg)
             return softmax_xent_chunked(h, self._unembed(params), batch["labels"], cfg.loss_chunk)
         x, labels = self._embed_inputs(params, batch)
-        h = tf.forward_train(params, x, cfg, n_groups=n_groups)
+        h = tf.forward_train(params, x, cfg)
         return softmax_xent_chunked(h, self._unembed(params), labels, cfg.loss_chunk)
 
     # -- serve --------------------------------------------------------------
-    def prefill(self, params, batch, s_max: int, *, n_groups: int = 0):
-        """Returns (last-position logits (B, V), caches)."""
+    def prefill(self, params, batch, s_max: int, *, counts: bool = False):
+        """Returns (last-position logits (B, V), caches), and with ``counts``
+        the FFNs' counts summed over layers (a MoE model's ``expert_rows``,
+        the token-expert rows its held experts computed; ``{}`` otherwise)."""
         cfg = self.cfg
+        c = {}
         if cfg.encdec:
             enc_out = encdec_mod.encode(params, batch["enc_frames"].astype(pdtype(cfg)), cfg)
             h, caches = encdec_mod.prefill(params, batch["tokens"], enc_out, cfg, s_max)
@@ -73,21 +77,25 @@ class Model:
             x = embed(batch["tokens"], params["embed"]).astype(pdtype(cfg))
             if cfg.vision_prefix:
                 x = jnp.concatenate([batch["vis_embeds"].astype(x.dtype), x], axis=1)
-            h, caches = tf.forward_prefill(params, x, cfg, s_max, n_groups=n_groups)
+            h, caches, c = tf.forward_prefill(params, x, cfg, s_max)
         logits = unembed_logits(h[:, -1], self._unembed(params))
-        return logits, caches
+        return (logits, caches, c) if counts else (logits, caches)
 
-    def decode(self, params, caches, tokens, pos, *, n_groups: int = 0):
-        """One decode step. tokens (B,1) int32; pos scalar int32 (absolute)."""
+    def decode(self, params, caches, tokens, pos, *, counts: bool = False):
+        """One decode step. tokens (B,1) int32; pos scalar int32 (absolute).
+        With ``counts``, also the FFNs' counts summed over layers (a MoE
+        model's ``experts_read``, the routed experts whose weights the step
+        read; ``{}`` otherwise)."""
         cfg = self.cfg
+        c = {}
         x = embed(tokens, params["embed"]).astype(pdtype(cfg))
         if cfg.encdec:
             x = x + jnp.take(params["pos_dec"], jnp.full((1,), pos), axis=0)[None, 0]
             h, caches = encdec_mod.decode_step(params, x, caches, pos, cfg)
         else:
-            h, caches = tf.forward_decode(params, x, caches, pos, cfg, n_groups=n_groups)
+            h, caches, c = tf.forward_decode(params, x, caches, pos, cfg)
         logits = unembed_logits(h, self._unembed(params))  # (B, 1, V)
-        return logits, caches
+        return (logits, caches, c) if counts else (logits, caches)
 
     # -- caches ---------------------------------------------------------------
     def cache_struct(self, batch: int, s_ctx: int) -> Any:

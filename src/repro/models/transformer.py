@@ -127,25 +127,34 @@ def _mixer_train(pl, x, cfg: ArchConfig, mixer: str):
     raise ValueError(mixer)
 
 
-def _ffn_apply(pl, x, cfg: ArchConfig, ffn: str, n_groups: int):
+def _ffn_apply(pl, x, cfg: ArchConfig, ffn: str, experts=None):
+    """The block's FFN: (output, counts). A MoE layer counts the token-expert
+    rows its held experts computed (``expert_rows``) or, given the group's
+    stacked experts and its layer index (``experts``, decode), the experts it
+    read (``experts_read``); a dense one counts nothing."""
     if ffn == "dense":
         from repro.models.layers import swiglu
 
-        return swiglu(x, pl["ffn"]["wg"], pl["ffn"]["wu"], pl["ffn"]["wd"])
+        return swiglu(x, pl["ffn"]["wg"], pl["ffn"]["wu"], pl["ffn"]["wd"]), {}
+    if ffn == "moe" and experts is None:
+        y, rows = moe_mod.moe_ffn(pl["ffn"], x, cfg)
+        return y, {"expert_rows": rows}
     if ffn == "moe":
-        return moe_mod.moe_ffn(pl["ffn"], x, cfg, n_groups=n_groups)
+        y, n_read = moe_mod.moe_decode(pl["ffn"], x, cfg, *experts)
+        return y, {"experts_read": n_read}
     raise ValueError(ffn)
 
 
-def block_train(pl, x, cfg: ArchConfig, mixer: str, ffn: str, n_groups: int):
+def block_train(pl, x, cfg: ArchConfig, mixer: str, ffn: str):
     h = x + _mixer_train(pl, rmsnorm(x, pl["ln1"], cfg.norm_eps), cfg, mixer)
     if ffn != "none":
-        h = h + _ffn_apply(pl, rmsnorm(h, pl["ln2"], cfg.norm_eps), cfg, ffn, n_groups)
+        h = h + _ffn_apply(pl, rmsnorm(h, pl["ln2"], cfg.norm_eps), cfg, ffn)[0]
     return h
 
 
-def block_prefill(pl, x, cfg, mixer, ffn, n_groups, s_max):
-    """Like block_train but also returns this layer's decode cache."""
+def block_prefill(pl, x, cfg, mixer, ffn, s_max):
+    """Like block_train but also returns this layer's decode cache and the
+    FFN's counts."""
     xin = rmsnorm(x, pl["ln1"], cfg.norm_eps)
     if mixer == "gqa":
         y = attn.gqa_train(pl["attn"], xin, cfg)
@@ -180,12 +189,16 @@ def block_prefill(pl, x, cfg, mixer, ffn, n_groups, s_max):
     else:
         raise ValueError(mixer)
     h = x + y
+    counts = {}
     if ffn != "none":
-        h = h + _ffn_apply(pl, rmsnorm(h, pl["ln2"], cfg.norm_eps), cfg, ffn, n_groups)
-    return h, cache
+        y, counts = _ffn_apply(pl, rmsnorm(h, pl["ln2"], cfg.norm_eps), cfg, ffn)
+        h = h + y
+    return h, cache, counts
 
 
-def block_decode(pl, x, cache, pos, cfg, mixer, ffn, n_groups):
+def block_decode(pl, x, cache, pos, cfg, mixer, ffn, experts=None):
+    """One decode step of a layer: (hidden, cache, the FFN's counts).
+    ``experts`` as ``_ffn_apply`` takes it, for a MoE layer."""
     xin = rmsnorm(x, pl["ln1"], cfg.norm_eps)
     if mixer == "gqa":
         y, cache = attn.gqa_decode(pl["attn"], xin, cache, pos, cfg)
@@ -202,9 +215,11 @@ def block_decode(pl, x, cache, pos, cfg, mixer, ffn, n_groups):
     else:
         raise ValueError(mixer)
     h = x + y
+    counts = {}
     if ffn != "none":
-        h = h + _ffn_apply(pl, rmsnorm(h, pl["ln2"], cfg.norm_eps), cfg, ffn, n_groups)
-    return h, cache
+        y, counts = _ffn_apply(pl, rmsnorm(h, pl["ln2"], cfg.norm_eps), cfg, ffn, experts)
+        h = h + y
+    return h, cache, counts
 
 
 # ---------------------------------------------------------------------------
@@ -222,7 +237,7 @@ def _maybe_remat(fn, cfg: ArchConfig):
     return jax.checkpoint(fn, policy=_REMAT_POLICIES[cfg.remat], prevent_cse=False)
 
 
-def forward_train(params, x, cfg: ArchConfig, *, n_groups: int = 0):
+def forward_train(params, x, cfg: ArchConfig):
     """x: (B,S,E) embedded inputs -> final hidden (B,S,E)."""
     from repro.distributed.ctx import constrain
 
@@ -231,39 +246,56 @@ def forward_train(params, x, cfg: ArchConfig, *, n_groups: int = 0):
         gp = params["blocks"][gname]
 
         def body(h, pl, mixer=mixer, ffn=ffn):
-            h = block_train(pl, h, cfg, mixer, ffn, n_groups)
+            h = block_train(pl, h, cfg, mixer, ffn)
             return constrain(h, "resid"), None
 
         x, _ = jax.lax.scan(_maybe_remat(body, cfg), x, gp)
     return rmsnorm(x, params["final_norm"], cfg.norm_eps)
 
 
-def forward_prefill(params, x, cfg: ArchConfig, s_max: int, *, n_groups: int = 0):
-    """Returns (final hidden, caches) — caches stacked per group."""
-    caches = {}
+def _sum_counts(total: dict, per_layer: dict) -> None:
+    for k, v in per_layer.items():
+        total[k] = total.get(k, 0) + jnp.sum(v)
+
+
+def forward_prefill(params, x, cfg: ArchConfig, s_max: int):
+    """Returns (final hidden, caches, counts) — caches stacked per group,
+    the FFNs' counts summed over layers."""
+    caches, counts = {}, {}
     for gname, n, mixer, ffn in block_groups(cfg):
         gp = params["blocks"][gname]
 
         def body(h, pl, mixer=mixer, ffn=ffn):
-            h2, cache = block_prefill(pl, h, cfg, mixer, ffn, n_groups, s_max)
-            return h2, cache
+            h2, cache, c = block_prefill(pl, h, cfg, mixer, ffn, s_max)
+            return h2, (cache, c)
 
-        x, gcache = jax.lax.scan(body, x, gp)
+        x, (gcache, gcounts) = jax.lax.scan(body, x, gp)
         caches[gname] = gcache
-    return rmsnorm(x, params["final_norm"], cfg.norm_eps), caches
+        _sum_counts(counts, gcounts)
+    return rmsnorm(x, params["final_norm"], cfg.norm_eps), caches, counts
 
 
-def forward_decode(params, x, caches, pos, cfg: ArchConfig, *, n_groups: int = 0):
-    """x: (B,1,E). Returns (final hidden (B,1,E), new caches)."""
-    new_caches = {}
+def forward_decode(params, x, caches, pos, cfg: ArchConfig):
+    """x: (B,1,E). Returns (final hidden (B,1,E), new caches, counts).
+
+    A MoE group's routed experts stay out of the scan's per-layer slices:
+    each layer indexes the group's stacked weights for the experts its
+    tokens routed to (``moe.moe_decode``), so a step reads no others."""
+    new_caches, counts = {}, {}
     for gname, n, mixer, ffn in block_groups(cfg):
         gp = params["blocks"][gname]
+        xs, stacked = (gp, caches[gname]), None
+        if ffn == "moe":
+            stacked = {k: gp["ffn"][k] for k in moe_mod.EXPERT_WEIGHTS}
+            rest = {k: v for k, v in gp["ffn"].items() if k not in stacked}
+            xs = ({**gp, "ffn": rest}, caches[gname], jnp.arange(n))
 
-        def body(h, xs, mixer=mixer, ffn=ffn):
-            pl, cache = xs
-            h2, cache2 = block_decode(pl, h, cache, pos, cfg, mixer, ffn, n_groups)
-            return h2, cache2
+        def body(h, xs, mixer=mixer, ffn=ffn, stacked=stacked):
+            experts = None if stacked is None else (stacked, xs[2])
+            h2, cache2, c = block_decode(xs[0], h, xs[1], pos, cfg, mixer, ffn, experts)
+            return h2, (cache2, c)
 
-        x, gcache = jax.lax.scan(body, x, (gp, caches[gname]))
+        x, (gcache, gcounts) = jax.lax.scan(body, x, xs)
         new_caches[gname] = gcache
-    return rmsnorm(x, params["final_norm"], cfg.norm_eps), new_caches
+        _sum_counts(counts, gcounts)
+    return rmsnorm(x, params["final_norm"], cfg.norm_eps), new_caches, counts

@@ -147,39 +147,67 @@ class ModelEngine:
         # where decode runs: the device the weights landed on
         self.platform = jax.tree_util.tree_leaves(self.params)[0].devices().pop().platform
         self.vocab = self.cfg.vocab
-        # bound to the model, not to self: a cycle through the engine would
-        # keep its weights on the device until the garbage collector ran
-        self._decode_fn = jax.jit(model.decode)
+        # the jitted decode step of whichever model the engine holds, built
+        # at the first decode after the model changes
+        self._decode_for = (None, None)
 
     def spec(self) -> str:
         return f"model:{self.arch}:{'smoke' if self.smoke else 'full'}:seed={self.seed}"
+
+    def _decode_step(self):
+        """``Model.decode(counts=True)`` of the current model, jitted once a model."""
+        import jax
+
+        if self._decode_for[0] is not self.model:
+            # bound to the model, not to self: a cycle through the engine would
+            # keep its weights on the device until the garbage collector ran
+            model = self.model
+
+            def decode(params, caches, tokens, pos):
+                return model.decode(params, caches, tokens, pos, counts=True)
+
+            self._decode_for = (model, jax.jit(decode))
+        return self._decode_for[1]
 
     def prefill(self, prompt, max_new: int) -> dict:
         import jax
         import jax.numpy as jnp
 
+        from repro import spans
+
         prompt = np.asarray(prompt, dtype=np.int32).reshape(-1)
         p, m = int(prompt.size), int(max_new)
-        logits, caches = self.model.prefill(
-            self.params, {"tokens": jnp.asarray(prompt[None, :])}, s_max=p + m
+        logits, caches, counts = self.model.prefill(
+            self.params, {"tokens": jnp.asarray(prompt[None, :])}, s_max=p + m, counts=True
         )
         jax.block_until_ready(logits)
         out = np.zeros(m, dtype=np.int32)
-        out[0] = first = int(jnp.argmax(logits[0]))
+        # a MoE model's counters ride on the token's copy to the host
+        first, counts = jax.device_get((jnp.argmax(logits[0]), counts))
+        if counts:
+            spans.annotate(tokens=p, **{k: int(v) for k, v in counts.items()})
+        out[0] = first = int(first)
         return {"caches": caches, "out": out, "prompt": prompt,
                 "pos": p, "done": 1, "tok": first, "step": 1}
 
     def decode(self, state: dict) -> dict:
+        import jax
         import jax.numpy as jnp
+
+        from repro import spans
 
         if is_done(state):
             return state
         pos, done = int(state["pos"]), int(state["done"])
         tok_in = jnp.asarray([[int(state["tok"])]], jnp.int32)
-        lg, caches = self._decode_fn(
+        lg, caches, counts = self._decode_step()(
             self.params, state["caches"], tok_in, jnp.asarray(pos, jnp.int32)
         )
-        tok = int(jnp.argmax(lg[0, -1]))
+        # a MoE model's counters ride on the token's copy to the host: no sync of their own
+        tok, counts = jax.device_get((jnp.argmax(lg[0, -1]), counts))
+        if counts:
+            spans.annotate(**{k: int(v) for k, v in counts.items()})
+        tok = int(tok)
         out = np.asarray(state["out"])
         out[done] = tok
         state.update(caches=caches, out=out, pos=pos + 1, done=done + 1,

@@ -48,7 +48,9 @@ def test_smoke_loss_prefill_decode(arch):
 
 
 @pytest.mark.parametrize(
-    "arch", ["qwen3-1.7b", "deepseek-v3-671b", "hymba-1.5b", "xlstm-1.3b", "whisper-tiny"]
+    "arch",
+    ["qwen3-1.7b", "deepseek-v3-671b", "moonlight-16b-a3b", "hymba-1.5b", "xlstm-1.3b",
+     "whisper-tiny"],
 )
 def test_decode_matches_teacher_forcing(arch):
     """Greedy decode logits at position t == train-forward logits at t.
@@ -105,6 +107,7 @@ def test_param_counts_match_assignment():
         "command-r-plus-104b": (95e9, 115e9),
         "granite-moe-1b-a400m": (0.8e9, 1.6e9),
         "deepseek-v3-671b": (600e9, 720e9),
+        "moonlight-16b-a3b": (5.0e9, 5.3e9),  # one expert-parallel rank of four
         "hymba-1.5b": (1.2e9, 2.2e9),
         "xlstm-1.3b": (1.0e9, 2.0e9),  # our mLSTM block carries q/k/v/og projs
         "internvl2-76b": (65e9, 80e9),
