@@ -147,29 +147,47 @@ class ModelEngine:
         # where decode runs: the device the weights landed on
         self.platform = jax.tree_util.tree_leaves(self.params)[0].devices().pop().platform
         self.vocab = self.cfg.vocab
-        # the jitted decode step of whichever model the engine holds, built
-        # at the first decode after the model changes
-        self._decode_for = (None, None)
+        # the jitted programs of whichever model the engine holds, each built
+        # at its first use after the model changes: (model, {key: program})
+        self._programs = (None, {})
 
     def spec(self) -> str:
         return f"model:{self.arch}:{'smoke' if self.smoke else 'full'}:seed={self.seed}"
 
-    def _decode_step(self):
-        """``Model.decode(counts=True)`` of the current model, jitted once a model."""
+    def _program(self, key, make):
+        """The jitted ``make(model)`` of the current model, once a model and
+        ``key``; and whether this call built it.
+
+        ``make`` gets the model, not the engine: a program closing over the
+        engine would keep its weights on the device until the garbage
+        collector ran."""
         import jax
 
-        if self._decode_for[0] is not self.model:
-            # bound to the model, not to self: a cycle through the engine would
-            # keep its weights on the device until the garbage collector ran
-            model = self.model
+        model, programs = self._programs
+        if model is not self.model:
+            model, programs = self.model, {}
+            self._programs = (model, programs)
+        if key in programs:
+            return programs[key], False
+        programs[key] = program = jax.jit(make(model))
+        return program, True
 
+    def _decode_step(self):
+        """``Model.decode(counts=True)`` of the current model, jitted once a model."""
+
+        def make(model):
             def decode(params, caches, tokens, pos):
                 return model.decode(params, caches, tokens, pos, counts=True)
 
-            self._decode_for = (model, jax.jit(decode))
-        return self._decode_for[1]
+            return decode
+
+        return self._program("decode", make)[0]
 
     def prefill(self, prompt, max_new: int) -> dict:
+        """The prompt's caches and first token, by a program compiled once a
+        (prompt length, cache length): an admit after the first of its shape
+        runs the device work alone. The span open around it gets the
+        counter ``compiled``, 1 where this call built the program."""
         import jax
         import jax.numpy as jnp
 
@@ -177,15 +195,26 @@ class ModelEngine:
 
         prompt = np.asarray(prompt, dtype=np.int32).reshape(-1)
         p, m = int(prompt.size), int(max_new)
-        logits, caches, counts = self.model.prefill(
-            self.params, {"tokens": jnp.asarray(prompt[None, :])}, s_max=p + m, counts=True
-        )
-        jax.block_until_ready(logits)
-        out = np.zeros(m, dtype=np.int32)
-        # a MoE model's counters ride on the token's copy to the host
-        first, counts = jax.device_get((jnp.argmax(logits[0]), counts))
+        s_max = p + m
+
+        def make(model):
+            # the weights are an argument: closed over, they would be constants
+            def prefill(params, tokens):
+                logits, caches, counts = model.prefill(params, {"tokens": tokens},
+                                                       s_max=s_max, counts=True)
+                return jnp.argmax(logits[0]), caches, counts
+
+            return prefill
+
+        program, built = self._program(("prefill", p, s_max), make)
+        # the device work ends inside the caller's span, not in what follows
+        first, caches, counts = jax.block_until_ready(
+            program(self.params, jnp.asarray(prompt[None, :])))
+        first, counts = jax.device_get((first, counts))
+        spans.annotate(compiled=int(built))
         if counts:
             spans.annotate(tokens=p, **{k: int(v) for k, v in counts.items()})
+        out = np.zeros(m, dtype=np.int32)
         out[0] = first = int(first)
         return {"caches": caches, "out": out, "prompt": prompt,
                 "pos": p, "done": 1, "tok": first, "step": 1}

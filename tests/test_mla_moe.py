@@ -207,9 +207,15 @@ def test_engine_annotates_its_spans():
         state = eng.prefill(np.arange(10), 4)
     pre = spans.last()
     assert pre.attrs["tokens"] == 10 and 0 < pre.attrs["expert_rows"] <= 10 * 2 * 2
+    assert pre.attrs["compiled"] == 1  # the first prefill of its shape builds the program
     with spans.span("engine.decode"):
         eng.decode(state)
     assert 0 <= spans.last().attrs["experts_read"] <= 2 * 2
+    with spans.span("engine.prefill"):
+        eng.prefill(np.arange(10, 20), 4)
+    again = spans.last()
+    assert again.attrs["compiled"] == 0 and again.attrs["tokens"] == 10
+    assert 0 < again.attrs["expert_rows"] <= 10 * 2 * 2
 
 
 def test_share_counts():

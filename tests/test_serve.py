@@ -14,8 +14,10 @@ Process-spawning tests use the same SIGALRM guard as tests/test_fabric.py.
 import os
 import signal
 
+import numpy as np
 import pytest
 
+from repro import spans
 from repro.core import DHP, NBS
 from repro.core.cmi import restore_cmi
 from repro.core.jobstore import JobStore, STATUS_FINISHED
@@ -87,6 +89,113 @@ def test_model_engine_deterministic_rebuild():
     b = run_reference(make_engine("model:qwen3-1.7b:smoke:seed=0"), reqs)
     assert a == b
     assert len(a["m0"]) == 4
+
+
+def _prefill_compiled(eng, prompt, max_new):
+    """``eng.prefill`` inside an ``engine.prefill`` span, as ``ServeHost.admit``
+    calls it: (state, the span's ``compiled`` counter)."""
+    with spans.span("engine.prefill"):
+        state = eng.prefill(prompt, max_new)
+    return state, spans.last().attrs["compiled"]
+
+
+def test_model_engine_compiles_prefill_once_a_shape():
+    eng = make_engine("model:qwen3-1.7b:smoke:seed=0")
+    seen = [_prefill_compiled(eng, prompt, m)[1] for prompt, m in [
+        ([3, 1, 4, 1, 5], 4), ([2, 7, 1, 8, 2], 4),  # one shape: built, then reused
+        ([3, 1, 4, 1, 5, 9], 4),  # another prompt length
+        ([3, 1, 4, 1, 5], 6),  # another answer length: another cache length
+        ([9, 9, 9, 9, 9], 6)]]
+    assert seen == [1, 0, 1, 1, 0]
+
+
+def _float32_engine(arch):
+    """A smoke engine of ``arch`` rebuilt in float32, as the benchmark
+    harness rebuilds an engine's model and weights."""
+    import jax
+
+    from repro.models import Model
+
+    eng = make_engine(f"model:{arch}:smoke:seed=0")
+    eng.cfg = eng.cfg.with_(dtype="float32")
+    eng.model = model = Model(eng.cfg)
+    eng.params = jax.jit(lambda key: model.init(key)[0])(jax.random.PRNGKey(1))
+    return eng
+
+
+@pytest.mark.parametrize("arch", ["qwen3-1.7b", "moonlight-16b-a3b"])
+def test_compiled_prefill_equals_the_eager_model_prefill(arch):
+    import jax
+    import jax.numpy as jnp
+
+    eng = _float32_engine(arch)
+    prompt = np.arange(3, 40, dtype=np.int32) % eng.vocab
+    state, compiled = _prefill_compiled(eng, prompt, 5)
+    counts = {k: v for k, v in spans.last().attrs.items() if k not in ("compiled", "tokens")}
+    logits, caches, want = eng.model.prefill(
+        eng.params, {"tokens": jnp.asarray(prompt[None, :])}, s_max=42, counts=True)
+    assert compiled == 1
+    assert state["tok"] == int(jnp.argmax(logits[0])) and state["pos"] == 37
+    assert counts == {k: int(v) for k, v in want.items()}
+    assert bool(counts) == (arch == "moonlight-16b-a3b")
+    jax.tree_util.tree_map(np.testing.assert_array_equal, state["caches"], caches)
+
+
+def test_run_reference_transcripts_match_the_eager_prefill():
+    """The compiled prefill leaves every transcript as the eager
+    ``Model.prefill`` followed by the engine's decode makes it."""
+    import jax.numpy as jnp
+
+    eng = make_engine("model:qwen3-1.7b:smoke:seed=0")
+    reqs = [{"id": f"m{i}", "prompt": [3 + i, 1, 4, 1, 5][: 3 + i], "max_new": 4 + i}
+            for i in range(3)]
+    want = {}
+    for req in reqs:
+        prompt = np.asarray(req["prompt"], np.int32)
+        logits, caches = eng.model.prefill(eng.params, {"tokens": jnp.asarray(prompt[None, :])},
+                                           s_max=prompt.size + req["max_new"])
+        tok = int(jnp.argmax(logits[0]))
+        out = np.zeros(req["max_new"], np.int32)
+        out[0] = tok
+        state = {"caches": caches, "out": out, "prompt": prompt,
+                 "pos": prompt.size, "done": 1, "tok": tok, "step": 1}
+        while int(state["done"]) < req["max_new"]:
+            state = eng.decode(state)
+        want[req["id"]] = [int(t) for t in state["out"]]
+    assert run_reference(eng, reqs) == want
+
+
+def test_replaced_model_gets_programs_of_its_own():
+    """A model put in place of the engine's, as the benchmark harness puts
+    one, is traced anew; the program of the model it replaced never runs."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.models import Model
+
+    class Traced(Model):
+        def __init__(self, cfg):
+            super().__init__(cfg)
+            self.traced = 0
+
+        def prefill(self, *args, **kw):
+            self.traced += 1
+            return super().prefill(*args, **kw)
+
+    eng = make_engine("model:qwen3-1.7b:smoke:seed=0")
+    eng.model = old = Traced(eng.cfg)
+    prompt = np.asarray([3, 1, 4, 1, 5], np.int32)
+    before, compiled = _prefill_compiled(eng, prompt, 4)
+    assert compiled == 1
+    # another rotary base: the same tree of weights, other caches
+    eng.cfg = eng.cfg.with_(rope_theta=eng.cfg.rope_theta / 7)
+    eng.model = new = Traced(eng.cfg)
+    state, compiled = _prefill_compiled(eng, prompt, 4)
+    assert compiled == 1 and (old.traced, new.traced) == (1, 1)
+    _, want = Model(eng.cfg).prefill(eng.params, {"tokens": jnp.asarray(prompt[None, :])}, s_max=9)
+    jax.tree_util.tree_map(np.testing.assert_array_equal, state["caches"], want)
+    assert not np.array_equal(before["caches"]["g0"]["k"], state["caches"]["g0"]["k"])
+    assert _prefill_compiled(eng, prompt, 4)[1] == 0 and new.traced == 1
 
 
 # ---------------------------------------------------------------------------
